@@ -49,10 +49,10 @@ def measure_block(mode_kwargs, chunks: int) -> float:
     """Wall time (us) of pre-norm + MLP (f3/f4 boundaries) on the host mesh."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.atp import atp_linear, make_context
-    from repro.core.compat import shard_map
     from repro.core.mesh import MeshTopo
     from repro.models import layers as L
 

@@ -98,7 +98,7 @@ def table3_overlap():
     schedule's independence structure, reported as relative time."""
     import jax
     import jax.numpy as jnp
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.atp import atp_linear, make_context

@@ -18,8 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-
-from repro.core.compat import shard_map
+from jax import shard_map
 
 from repro.core.atp import atp_linear, make_context
 from repro.core.mesh import MeshTopo

@@ -40,6 +40,7 @@ import math
 from collections import defaultdict
 
 from repro.configs.base import ModelConfig, segments
+from repro.core.atp import gather_features_op
 
 ACT = "bfloat16"
 F32 = "float32"
@@ -482,7 +483,7 @@ def _zamba_super(v: View, cfg: ModelConfig, b: int, S: int, decode: bool,
 
 
 def _xlstm_super(v: View, cfg: ModelConfig, b: int, S: int,
-                 inner: int) -> list[Exp]:
+                 inner: int, decode: bool) -> list[Exp]:
     d_inner = int(cfg.ssm.proj_factor * cfg.d_model)
     nh = cfg.num_heads
     dk = (d_inner // nh) // 2
@@ -501,8 +502,10 @@ def _xlstm_super(v: View, cfg: ModelConfig, b: int, S: int,
     if v.tp_axes:  # down projection: all-reduce over BOTH mesh dims
         mlstm.append(Exp("psum", v.tp_axes, 1, b * S * h, v.act))
     out = _times(mlstm, inner - 1)
-    if v.ax2:  # sLSTM runs on full-h replicated activations
-        out.append(Exp("all_gather", (v.ax2,), 1, b * S * h, v.act))
+    if v.ax2:  # sLSTM runs on full-h replicated activations (invariant
+        # over ax2 in decode, where a cached state meets them)
+        out.append(Exp(gather_features_op(decode), (v.ax2,), 1, b * S * h,
+                       v.act))
     return out
 
 
@@ -519,7 +522,7 @@ def _layer_exps(seg, v: View, cfg: ModelConfig, b: int, S: int,
     if seg.kind == "zamba":
         return _zamba_super(v, cfg, b, S, decode, seg.inner)
     if seg.kind == "xlstm":
-        return _xlstm_super(v, cfg, b, S, seg.inner)
+        return _xlstm_super(v, cfg, b, S, seg.inner, decode)
     raise ValueError(seg.kind)
 
 

@@ -1,13 +1,12 @@
 """Replication (vma) lint: prove shard_map ``out_specs`` honest.
 
-ATP runs several build paths with jax's own per-eqn replication checker
-disabled (``check_vma=False``): every build on the legacy-jax floor
-(where the upstream checker rejects ppermute rings outright) and every
-ring/collective-matmul plan even on current jax.  This module closes
+ATP runs some build paths with jax's own per-eqn replication checker
+disabled (``check_vma=False``): every ring/collective-matmul plan, whose
+ppermute rings the upstream checker cannot certify.  This module closes
 that gap: it walks the traced jaxpr of a built step, finds each
 ``shard_map`` eqn, and data-flows a *replication set* — the mesh axes
 over which a value is guaranteed identical across ranks — from the
-``in_names`` to every output, then checks each output's ``out_names``:
+``in_specs`` to every output, then checks each output's ``out_specs``:
 an axis the spec does NOT mention is a claim of replication, and the
 lint errors if the value may actually vary over it.
 
@@ -45,8 +44,9 @@ import re
 from itertools import chain
 from typing import Any
 
-import jax
-from jax import core as jcore
+from jax.extend import core as jcore
+
+from repro.analysis.signature import prim_name
 
 #: scope -> net effect on the replication set when a value escapes it
 _SCOPE_RE = re.compile(r"^(ring_ar|ring_ag|ring_rs|cm_rs|cm_ag)\[(.+)\]$")
@@ -79,7 +79,7 @@ class ShardMapReport:
     mesh_axes: tuple[str, ...]
     errors: tuple[ReplicationError, ...]
     out_rep: tuple[frozenset, ...]
-    check_rep: bool
+    check_vma: bool
 
     @property
     def ok(self) -> bool:
@@ -148,7 +148,6 @@ class _RepWalker:
     def run(self, jaxpr: jcore.Jaxpr, in_vals: list[_Val],
             path: tuple[str, ...]) -> list[_Val]:
         env: dict[Any, _Val] = {}
-        drop = getattr(jcore, "DropVar", ())
         for v in jaxpr.constvars:
             env[v] = self.full
         for v, val in zip(jaxpr.invars, in_vals):
@@ -162,7 +161,7 @@ class _RepWalker:
         for eqn in jaxpr.eqns:
             p = path + _stack_components(eqn)
             scopes = _scopes_in(p)
-            name = eqn.primitive.name
+            name = prim_name(eqn)
             ins = [read(v, scopes) for v in eqn.invars]
             inter = frozenset.intersection(*ins) if ins \
                 else frozenset(self.axes)
@@ -186,8 +185,7 @@ class _RepWalker:
             else:
                 outs = self._generic(eqn, p, scopes, env, inter, read)
             for v, val in zip(eqn.outvars, outs):
-                if not isinstance(v, drop):
-                    env[v] = val
+                env[v] = val
         return [_Val(read(v, ()), ()) if isinstance(v, jcore.Literal)
                 else env.get(v, self.full).escaped(())
                 for v in jaxpr.outvars]
@@ -252,8 +250,11 @@ class _RepWalker:
         return [_Val(inter, scopes)] * len(eqn.outvars)
 
 
-def _names_to_axes(names: dict) -> frozenset:
-    return frozenset(chain.from_iterable(names.values()))
+def _spec_axes(spec) -> frozenset:
+    """Mesh axes a PartitionSpec shards over (entries: None, a name, or a
+    tuple of names)."""
+    return frozenset(chain.from_iterable(
+        e if isinstance(e, tuple) else (e,) for e in spec if e is not None))
 
 
 def _find_shard_maps(jaxpr: jcore.Jaxpr, out: list) -> None:
@@ -283,24 +284,23 @@ def analyze_shard_maps(fn_or_jaxpr: Any, *abstract_args) -> list[ShardMapReport]
     for eqn in eqns:
         mesh = eqn.params["mesh"]
         axes = tuple(mesh.axis_names)
-        auto = set(eqn.params.get("auto", ()) or ())
-        manual = frozenset(a for a in axes if a not in auto)
+        manual = frozenset(eqn.params["manual_axes"])
         # a size-1 mesh axis cannot carry variance: specs may still name
         # it (they are written against the axis NAMES, not the degrees),
         # so it is replicated by construction everywhere
         trivial = frozenset(a for a in manual
                             if dict(mesh.shape).get(a, 1) == 1)
         body = _sub_jaxpr(eqn.params["jaxpr"])
-        in_vals = [_Val((manual - _names_to_axes(nm)) | trivial)
-                   for nm in eqn.params["in_names"]]
+        in_vals = [_Val((manual - _spec_axes(sp)) | trivial)
+                   for sp in eqn.params["in_specs"]]
         walker = _RepWalker(manual)
         out_vals = walker.run(body, in_vals, ())
         errors = []
         out_rep = []
-        for i, (nm, val) in enumerate(zip(eqn.params["out_names"], out_vals)):
+        for i, (sp, val) in enumerate(zip(eqn.params["out_specs"], out_vals)):
             rep = val.read(()) | trivial
             out_rep.append(rep)
-            claimed = manual - _names_to_axes(nm)
+            claimed = manual - _spec_axes(sp)
             for ax in sorted(claimed - rep):
                 errors.append(ReplicationError(
                     out_index=i, axis=ax,
@@ -308,7 +308,7 @@ def analyze_shard_maps(fn_or_jaxpr: Any, *abstract_args) -> list[ShardMapReport]
                     actual=tuple(sorted(rep))))
         reports.append(ShardMapReport(
             mesh_axes=axes, errors=tuple(errors), out_rep=tuple(out_rep),
-            check_rep=bool(eqn.params.get("check_rep", False))))
+            check_vma=bool(eqn.params["check_vma"])))
     return reports
 
 
@@ -319,8 +319,8 @@ def verify_replication(fn_or_jaxpr: Any, *abstract_args,
     Returns error strings (empty == every replication claim is proven);
     raises AssertionError when ``strict`` and a claim fails.  This is the
     checker that stands in for jax's ``check_vma`` on the build paths
-    where that one is off — the legacy-jax floor and all ppermute-ring /
-    collective-matmul plans (see module docstring for the ring algebra).
+    where that one is off — all ppermute-ring / collective-matmul plans
+    (see module docstring for the ring algebra).
     """
     reports = analyze_shard_maps(fn_or_jaxpr, *abstract_args)
     if not reports:

@@ -33,7 +33,7 @@ from typing import Any, Callable, Iterable
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 #: primitives the extractor records (axis_index is free; pmean lowers to
 #: psum + divide so it never appears as its own primitive)
@@ -41,6 +41,19 @@ COLLECTIVE_PRIMS = frozenset({
     "psum", "pmax", "pmin", "ppermute", "all_gather", "reduce_scatter",
     "all_to_all",
 })
+
+#: vma-typed spellings of the same collectives (``lax.psum`` of a varying
+#: value traces to ``psum_invariant`` under check_vma)
+_PRIM_ALIASES = {"psum_invariant": "psum",
+                 "all_gather_invariant": "all_gather"}
+
+
+def prim_name(eqn) -> str:
+    """The eqn's primitive name, with vma spellings folded to the plain
+    collective they lower to."""
+    name = eqn.primitive.name
+    return _PRIM_ALIASES.get(name, name)
+
 
 _SEG_RE = re.compile(r"^seg\d+:[a-z_]+$")
 _SITE_RE = re.compile(r"^(ring_rs|ring_ag|ring_ar|cm_rs|cm_ag|quant|wireq)\[")
@@ -162,7 +175,7 @@ def _aval_elems(var) -> int:
 
 def _payload(eqn) -> tuple[int, str]:
     """(elements, dtype) under the HLO-matching byte convention."""
-    name = eqn.primitive.name
+    name = prim_name(eqn)
     if name in ("psum", "pmax", "pmin", "reduce_scatter"):
         # all-reduce: result == operand; reduce-scatter: result x group
         elems = sum(_aval_elems(v) for v in eqn.invars
@@ -221,7 +234,7 @@ class _Walker:
              path: tuple[str, ...]) -> None:
         for eqn in jaxpr.eqns:
             p = path + _stack_components(eqn)
-            name = eqn.primitive.name
+            name = prim_name(eqn)
             if name in COLLECTIVE_PRIMS:
                 elems, dtype = _payload(eqn)
                 region, backward, quant, site = _attribution(p)

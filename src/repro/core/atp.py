@@ -332,6 +332,12 @@ class ATPContext:
                 or any(s.boundary_mode == "ring" for s in self.segment_plans))
 
     @property
+    def any_quant_wire(self) -> bool:
+        """True if any segment (or the default knobs) quantizes its wire."""
+        return (self.wire_dtype != "bf16"
+                or any(s.wire_dtype != "bf16" for s in self.segment_plans))
+
+    @property
     def any_seq_parallel(self) -> bool:
         """True if any knob — the scalar default (which broadcasts to
         uncovered kinds) or any per-segment entry — requests the
@@ -406,17 +412,17 @@ def atp_boundary(x, axis: str | None):
 def vma_rewrite_active(ctx) -> bool:
     """True when jax's vma rewrite types this build's shard_map bodies.
 
-    With the rewrite active (jax>=0.6 AND no ring boundary in any
-    segment's plan — the same condition under which whole-step shard_maps
-    pass ``check_vma=True``), jax inserts ``pvary`` casts wherever a
+    With the rewrite active, jax inserts ``pvary`` casts wherever a
     replicated value meets varying data, and the transpose of ``pvary``
     is exactly the gradient psum that :func:`grad_sync` supplies by hand.
-    Callers use this to avoid double-reducing on rewrite builds and to
-    decide ``check_vma`` for whole-step shard_maps (one source of truth).
+    It is active unless some segment's plan runs a ring boundary or a
+    quantized wire: both are custom-VJP collectives whose backward runs a
+    mirrored (rank-partial) cotangent reduction, which vma typing cannot
+    express, so those builds pass ``check_vma=False``.  Callers use this
+    to avoid double-reducing on rewrite builds and to decide
+    ``check_vma`` for whole-step shard_maps (one source of truth).
     """
-    from repro.core.compat import LEGACY_REP_CHECKER
-
-    return not LEGACY_REP_CHECKER and not ctx.any_ring
+    return not (ctx.any_ring or ctx.any_quant_wire)
 
 
 def grad_sync(ctx, x, axes):
@@ -602,6 +608,26 @@ def atp_linear(
     if b is not None:
         y = y + b
     return y
+
+
+def gather_features(ctx, x, invariant: bool):
+    """Tiled all-gather of ``x``'s last dim over ax2.
+
+    ``invariant`` types the result replicated over ax2, as a recurrence
+    that carries a cached state needs.  jax 0.9 has no public invariant
+    all-gather, so that case places the local shard in zeros and psums:
+    an all-reduce of the full width, about twice an all-gather's bytes.
+    :func:`gather_features_op` names the collective each case emits."""
+    if not invariant:
+        return lax.all_gather(x, ctx.ax2, axis=-1, tiled=True)
+    full = jnp.zeros(x.shape[:-1] + (x.shape[-1] * ctx.d2,), x.dtype)
+    return lax.psum(lax.dynamic_update_slice_in_dim(
+        full, x, ctx.index2() * x.shape[-1], axis=x.ndim - 1), ctx.ax2)
+
+
+def gather_features_op(invariant: bool) -> str:
+    """The collective :func:`gather_features` emits."""
+    return "psum" if invariant else "all_gather"
 
 
 def shard_slice(x, index, nshards: int, dim: int):

@@ -340,7 +340,7 @@ def _measure_factorization(d1: int, d2: int, payload_bytes: int,
     from jax.sharding import PartitionSpec as P
 
     from repro.core import overlap
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from repro.core.mesh import tp_axis_names
 
     topo = atp_topo(1, d1, d2)

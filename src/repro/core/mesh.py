@@ -15,8 +15,6 @@ from typing import Sequence
 import jax
 import numpy as np
 
-from repro.core import compat
-
 # Canonical axis names used throughout the framework.
 AXIS_POD = "pod"      # across pods (DCN)
 AXIS_DATA = "data"    # data parallel (within pod)
@@ -24,6 +22,19 @@ AXIS_TP1 = "tp1"      # first dim of the ATP 2D device mesh (d1)
 AXIS_TP2 = "tp2"      # second dim of the ATP 2D device mesh (d2)
 # The required production mesh uses a single "model" axis == ATP (N, 1).
 AXIS_MODEL = "model"
+
+
+def _auto(n: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n
+
+
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
+              *, devices: Sequence[jax.Device] | None = None):
+    """``jax.make_mesh`` with every axis ``Auto``: the shard_map bodies
+    here do their own collectives, so no axis may be Explicit."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=_auto(len(tuple(axis_shapes))),
+                         devices=devices)
 
 
 def factorizations(n: int) -> list[tuple[int, int]]:
@@ -81,13 +92,14 @@ class MeshTopo:
     def build(self, devices: Sequence[jax.Device] | None = None) -> jax.sharding.Mesh:
         """Materialize into a jax Mesh (touches device state)."""
         if devices is None:
-            return compat.make_mesh(self.shape, self.names)
-        return compat.mesh_from_devices(
-            np.asarray(devices)[: self.size], self.shape, self.names)
+            return make_mesh(self.shape, self.names)
+        arr = np.asarray(devices)[: self.size].reshape(self.shape)
+        return jax.sharding.Mesh(arr, self.names,
+                                 axis_types=_auto(len(self.shape)))
 
     def abstract(self) -> jax.sharding.AbstractMesh:
         """AbstractMesh — enough for sharding specs / eval_shape, no devices."""
-        return compat.abstract_mesh(self.shape, self.names)
+        return jax.sharding.AbstractMesh(self.shape, self.names)
 
 
 def production_topo(multi_pod: bool = False) -> MeshTopo:

@@ -19,14 +19,10 @@ ATP mesh (`atp_topo(..., pods=S)` + stage_fn built from ATP layers).
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable
 
-import jax
 import jax.numpy as jnp
 from jax import lax
-
-from repro.core import compat
 
 
 def gpipe_forward(
@@ -38,7 +34,7 @@ def gpipe_forward(
     """Returns [M, ...] pipeline outputs (valid on the LAST stage; other
     stages return zeros — callers typically ppermute/psum the result or
     compute the loss on the last stage and psum it)."""
-    S = compat.axis_size(axis)
+    S = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     M = x_micro.shape[0]
     T = M + S - 1                      # total ticks incl. bubble
@@ -67,35 +63,11 @@ def gpipe_forward(
         return (buf, outs), None
 
     # init carries varying over `axis` to match the tick outputs (vma)
-    buf0 = compat.pcast(jnp.zeros(micro_shape, x_micro.dtype), axis, to="varying")
-    outs0 = compat.pcast(jnp.zeros((M,) + micro_shape, x_micro.dtype), axis,
+    buf0 = lax.pcast(jnp.zeros(micro_shape, x_micro.dtype), axis, to="varying")
+    outs0 = lax.pcast(jnp.zeros((M,) + micro_shape, x_micro.dtype), axis,
                       to="varying")
     (_, outs), _ = lax.scan(tick, (buf0, outs0), jnp.arange(T))
     return outs
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _psum_of_locals(x, axis):
-    """psum whose backward is the identity.
-
-    For a loss of the form ``global = sum over ranks of local_r`` the true
-    cotangent of every ``local_r`` is the global cotangent itself.  Plain
-    ``lax.psum`` only transposes that way under the 0.6 vma type system; on
-    0.4.x its transpose inserts another psum (scaling grads by the axis
-    size), so the correct rule is pinned here explicitly.
-    """
-    return lax.psum(x, axis)
-
-
-def _psum_of_locals_fwd(x, axis):
-    return lax.psum(x, axis), None
-
-
-def _psum_of_locals_bwd(axis, _res, ct):
-    return (ct,)
-
-
-_psum_of_locals.defvjp(_psum_of_locals_fwd, _psum_of_locals_bwd)
 
 
 def gpipe_loss(
@@ -107,8 +79,8 @@ def gpipe_loss(
 ):
     """Pipeline forward + last-stage loss, psum'd to every stage (so
     jax.grad drives the full pipeline backward)."""
-    S = compat.axis_size(axis)
+    S = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     outs = gpipe_forward(stage_fn, stage_params, x_micro, axis)
     local = jnp.where(idx == S - 1, loss_fn(outs), 0.0)
-    return _psum_of_locals(local, axis)
+    return lax.psum(local, axis)

@@ -1,13 +1,12 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so correctness tests run on CPU;
-on a real TPU deployment set REPRO_KERNEL_INTERPRET=0 (or pass
-interpret=False) to execute the compiled kernels.
+The kernels compile for the TPU.  ``interpret=True`` runs them through the
+Pallas interpreter instead, which is how the CPU correctness tests call
+them; nothing switches to it implicitly.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -18,19 +17,11 @@ from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssd_scan as _ssd
 
 
-def _default_interpret() -> bool:
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
                                              "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
-                    block_q=128, block_k=128, interpret=None):
+                    block_q=128, block_k=128, interpret=False):
     """q: [b, sq, hq, d]; k/v: [b, sk, hkv, d] (GQA-repeated here)."""
-    interpret = _default_interpret() if interpret is None else interpret
     b, sq, hq, d = q.shape
     hkv = k.shape[2]
     if hq != hkv:
@@ -47,8 +38,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
-def rmsnorm(x, gamma, *, eps=1e-6, block_rows=128, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+def rmsnorm(x, gamma, *, eps=1e-6, block_rows=128, interpret=False):
     shape = x.shape
     out = _rn.rmsnorm(x.reshape(-1, shape[-1]), gamma, eps=eps,
                       block_rows=block_rows, interpret=interpret)
@@ -58,15 +48,13 @@ def rmsnorm(x, gamma, *, eps=1e-6, block_rows=128, interpret=None):
 @functools.partial(jax.jit, static_argnames=("activation", "block_m",
                                              "block_n", "block_k", "interpret"))
 def matmul(a, b, bias=None, *, activation=None, block_m=128, block_n=128,
-           block_k=128, interpret=None):
+           block_k=128, interpret=False):
     """a @ b with optional fused bias [N] + activation epilogue."""
-    interpret = _default_interpret() if interpret is None else interpret
     return _mm.matmul(a, b, bias, activation=activation, block_m=block_m,
                       block_n=block_n, block_k=block_k, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A_log, B, C, D, *, chunk=64, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+def ssd_scan(x, dt, A_log, B, C, D, *, chunk=64, interpret=False):
     return _ssd.ssd_scan(x, dt, A_log, B, C, D, chunk=chunk,
                          interpret=interpret)

@@ -33,8 +33,9 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.core.mesh import atp_topo
 from repro.core.plan import ParallelPlan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import (build_decode_step, build_paged_step,
-                                resolve_ctx)
+                                init_params_sharded, resolve_ctx)
 from repro.models import lm
 from repro.models.paging import PagedConfig
 from repro.runtime.server import Request, Server, ServerConfig
@@ -70,6 +71,33 @@ def serve(cfg, topo, params, prompts, max_new: int, max_seq: int,
     return np.stack(outs, axis=1)  # [B, max_new]
 
 
+def serving_view(plan: ParallelPlan | None, topo=None):
+    """``(plan, topo)`` the serving stack is built on.
+
+    With a plan, its ``decode_view()`` wins: serving is decode-dominated,
+    and prefill/decode share one set of sharded params and caches, so the
+    decode sub-plan's (d1, d2) is the serving mesh."""
+    if plan is None:
+        if topo is None:
+            raise TypeError("serving needs a plan or a topo")
+        return None, topo
+    view = plan.decode_view()
+    if (view.d1, view.d2) != (plan.d1, plan.d2):
+        log.info("decode sub-plan re-meshes serving: %s -> "
+                 "DeviceMesh(%d,%d)", plan.describe(), view.d1, view.d2)
+    return view, view.topo()
+
+
+def init_serving_params(cfg, key, plan: ParallelPlan | None = None,
+                        topo=None):
+    """Params created in place on the mesh ``make_paged_server`` builds
+    for the same ``plan``/``topo``, so handing them over moves nothing."""
+    plan, topo = serving_view(plan, topo)
+    ctx = resolve_ctx(topo, plan, decode=True)
+    return init_params_sharded(cfg, key, topo.build(),
+                               lm.param_specs(cfg, ctx))
+
+
 def make_paged_server(cfg, scfg: ServerConfig, params,
                       plan: ParallelPlan | None = None, topo=None):
     """Build the paged continuous-batching server on the serving mesh.
@@ -87,20 +115,12 @@ def make_paged_server(cfg, scfg: ServerConfig, params,
     """
     from repro.configs.base import segments
 
-    if plan is not None:
-        view = plan.decode_view()
-        if (view.d1, view.d2) != (plan.d1, plan.d2):
-            log.info("decode sub-plan re-meshes serving: %s -> "
-                     "DeviceMesh(%d,%d)", plan.describe(), view.d1, view.d2)
-        topo = view.topo()
-        dec = view.decode
-        if dec is not None:
-            scfg = dataclasses.replace(
-                scfg, speculate=scfg.speculate or dec.speculate,
-                prefix_cache=scfg.prefix_cache or dec.prefix_cache)
-        plan = view
-    elif topo is None:
-        raise TypeError("make_paged_server needs a plan or a topo")
+    plan, topo = serving_view(plan, topo)
+    if plan is not None and plan.decode is not None:
+        dec = plan.decode
+        scfg = dataclasses.replace(
+            scfg, speculate=scfg.speculate or dec.speculate,
+            prefix_cache=scfg.prefix_cache or dec.prefix_cache)
     recurrent = any(s.kind in lm.RECURRENT_STATE_KINDS
                     for s in segments(cfg))
     if scfg.speculate and (not cfg.mtp or recurrent):
@@ -178,15 +198,7 @@ def remesh_paged_server(server: Server, cfg, params,
     client's side a remesh is just a burst of re-prefill latency.
     Returns the new step ``info``.
     """
-    if plan is not None:
-        view = plan.decode_view()
-        if (view.d1, view.d2) != (plan.d1, plan.d2):
-            log.info("remesh: decode sub-plan wins: %s -> DeviceMesh(%d,%d)",
-                     plan.describe(), view.d1, view.d2)
-        topo = view.topo()
-        plan = view
-    elif topo is None:
-        raise TypeError("remesh_paged_server needs a plan or a topo")
+    plan, topo = serving_view(plan, topo)
     step_fn, init_caches, info = _build_paged_step_fn(
         cfg, server.cfg, params, topo, plan, devices=devices)
     server.reshape(step_fn, init_caches)
@@ -231,6 +243,7 @@ def main():
                     help="comm-matrix preset for --auto-atp")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -249,7 +262,8 @@ def main():
         log.info("ATP plan search picked %s", plan.describe())
     topo = plan.topo() if plan is not None else atp_topo(args.dp, args.d1,
                                                          args.d2)
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    params = init_serving_params(cfg, jax.random.PRNGKey(0), plan=plan,
+                                 topo=topo)
 
     rng = np.random.default_rng(0)
     if args.mode == "paged":

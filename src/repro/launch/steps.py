@@ -13,8 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.core.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import ModelConfig, ShapeConfig, segments
 from repro.core.atp import ATPContext, make_context
@@ -38,6 +37,17 @@ class StepInfo:
             is_leaf=lambda x: isinstance(x, P))
 
 
+def init_params_sharded(cfg: ModelConfig, key, mesh: jax.sharding.Mesh,
+                        pspecs):
+    """``lm.init_params`` compiled with the param shardings as outputs, so
+    each device only ever holds its own shards (an unsharded init puts the
+    whole model on the first device before any ``device_put``).  Same
+    values as the unsharded init for the same key."""
+    out = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
+                       is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(lambda k: lm.init_params(cfg, k), out_shardings=out)(key)
+
+
 def _dp_axes_spec(topo: MeshTopo):
     names = [a for a in ("pod", "data")
              if topo.has_axis(a) and topo.axis_size(a) > 1]
@@ -47,7 +57,11 @@ def _dp_axes_spec(topo: MeshTopo):
 def batch_pspecs(cfg: ModelConfig, topo: MeshTopo, kind: str):
     dp = _dp_axes_spec(topo)
     if cfg.frontend == "vision_patches":
-        ax2 = "tp2" if topo.has_axis("tp2") else None
+        # a size-1 tp2 is no model axis (the context drops it): sharding
+        # the embeds over it would type them varying where nothing
+        # reduces them
+        ax2 = ("tp2" if topo.has_axis("tp2") and topo.axis_size("tp2") > 1
+               else None)
         sp = {"embeds": P(dp, None, ax2), "positions3": P(None, dp, None)}
     else:
         sp = {"tokens": P(dp, None)}
@@ -122,10 +136,7 @@ def _check_vma(ctx: ATPContext) -> bool:
     """Ring boundaries decompose psums into ppermute rings whose outputs
     the vma type system labels *varying* (unlike lax.psum's invariant
     output), so the replication checker cannot certify them — numerical
-    equivalence is pinned by the bitwise-parity tests instead.  The legacy
-    (jax 0.4/0.5) checker additionally has no rep rules for the
-    custom_vjp ops every whole-step program contains (gpipe_loss, the
-    overlap collectives), so it is skipped wholesale there.  Ring in ANY
+    equivalence is pinned by the bitwise-parity tests instead.  Ring in ANY
     segment's plan disqualifies the whole step.  Delegates to
     :func:`repro.core.atp.vma_rewrite_active` — the same predicate gates
     the manual ``grad_sync`` barriers (no rewrite => manual psums), so

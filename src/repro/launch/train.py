@@ -10,13 +10,15 @@ The strategy is a ParallelPlan artifact end to end:
     # re-apply a saved plan bit-for-bit (train or serve)
     ... -m repro.launch.train --arch llama3-8b --plan plan.json
 
-Device count comes from the environment (single host: set
-XLA_FLAGS=--xla_force_host_platform_device_count=N before launch).
+The mesh is built over ``jax.devices()``: the attached chips, or on a
+CPU host the virtual devices that
+XLA_FLAGS=--xla_force_host_platform_device_count=N gives.
 """
 from __future__ import annotations
 
 import argparse
 import logging
+import tempfile
 import warnings
 
 import jax
@@ -29,7 +31,8 @@ from repro.core.calibrate import calibrate_mesh, recalibrate_surviving
 from repro.core.cost_model import LayerCommProfile
 from repro.core.plan import ParallelPlan, plan_search, replan_elastic
 from repro.data.pipeline import DataConfig, TokenSource
-from repro.launch.steps import build_train_step
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.steps import build_train_step, init_params_sharded
 from repro.models import lm
 from repro.optim import adamw
 from repro.runtime.membership import SingleObserverMembership
@@ -133,11 +136,13 @@ def make_elastic_trainer(cfg, plan: ParallelPlan, opt_cfg, trainer_cfg,
     live = {"plan": plan, "step": step_fn, "info": info, "ctx": info.ctx}
 
     def init_state():
+        # created in place, shard by shard (see init_params_sharded)
         inf, c = live["info"], live["ctx"]
-        params = lm.init_params(cfg, jax.random.PRNGKey(0))
-        opt = adamw.init_opt_state(params, inf.pspecs, c, opt_cfg.mode)
-        params = jax.device_put(params, inf.sharding(inf.pspecs))
-        opt = jax.device_put(opt, inf.sharding(inf.ospecs))
+        params = init_params_sharded(cfg, jax.random.PRNGKey(0), inf.mesh,
+                                     inf.pspecs)
+        opt = jax.jit(
+            lambda p: adamw.init_opt_state(p, inf.pspecs, c, opt_cfg.mode),
+            out_shardings=inf.sharding(inf.ospecs))(params)
         return params, opt
 
     def put_batch(host_batch):
@@ -278,12 +283,15 @@ def main():
     ap.add_argument("--opt-mode", default="zero1",
                     choices=["plain", "zero1", "compressed"])
     ap.add_argument("--chunks", type=int, default=1)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint (and resume) directory; default: a "
+                         "fresh temporary directory, so nothing is resumed")
     ap.add_argument("--ckpt-every", type=int, default=25)
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -314,9 +322,10 @@ def main():
                                 total_steps=args.steps)
     source = TokenSource(DataConfig(
         vocab_size=cfg.vocab_size, seq_len=args.seq, global_batch=args.batch))
+    ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="repro_ckpt_")
     trainer, live = make_elastic_trainer(
         cfg, plan, opt_cfg,
-        TrainerConfig(total_steps=args.steps, ckpt_dir=args.ckpt_dir,
+        TrainerConfig(total_steps=args.steps, ckpt_dir=ckpt_dir,
                       ckpt_every=args.ckpt_every),
         source, batch=args.batch, seq=args.seq)
     params, _ = trainer.run()

@@ -18,6 +18,7 @@ collectives) except the init/spec helpers.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -28,7 +29,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import (ModelConfig, Segment,  # noqa: F401 (re-export)
                                 ShapeConfig, segments)
-from repro.core import compat
 from repro.core.atp import (ATPContext, atp_boundary,
                             atp_reduce_scatter, seq_gather, seq_scatter,
                             shard_slice)
@@ -178,7 +178,13 @@ def _apply_block(kind: str, ctx, cfg, p, x, positions, plan, window, cache,
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnums=(0, 2))
 def init_params(cfg: ModelConfig, key, dtype=None) -> dict[str, Any]:
+    """Random params for ``cfg`` from ``key``.
+
+    Always one compiled program: op-by-op execution rounds a few scaled
+    draws differently, and the sharded init (``launch.steps.
+    init_params_sharded``) must give the same bits as this call."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, 16)
     h = cfg.d_model
@@ -192,9 +198,10 @@ def init_params(cfg: ModelConfig, key, dtype=None) -> dict[str, Any]:
                         / math.sqrt(h)).astype(dtype)
     for i, seg in enumerate(segments(cfg)):
         ks = jax.random.split(keys[2 + i], seg.count)
-        p[f"seg{i}"] = jax.tree.map(
-            lambda *xs: jnp.stack(xs),
-            *[_block_params(seg.kind, k, cfg, dtype) for k in ks])
+        # one traced block for the whole stack (the same values as one
+        # call per key): compile time does not grow with depth
+        p[f"seg{i}"] = jax.vmap(
+            lambda k, kind=seg.kind: _block_params(kind, k, cfg, dtype))(ks)
     if any(s.kind == "zamba" for s in segments(cfg)):
         k1, k2, k3 = jax.random.split(keys[14], 3)
         # two separate [h, h] projections (a single [2h, h] would break the
@@ -706,7 +713,7 @@ def forward(
     if cfg.moe is not None and ctx.dp_axes:
         # MoE aux loss varies with this rank's tokens -> type it varying
         # over the data axes so the scan carry is consistent
-        aux_total = compat.pcast(aux_total, ctx.dp_axes, to="varying")
+        aux_total = lax.pcast(aux_total, ctx.dp_axes, to="varying")
     new_caches = {} if caches is not None else None
 
     plan = (L.make_attn_plan(ctx, cfg.num_heads, cfg.num_kv_heads)

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.core.atp import ATPContext, grad_sync, shard_slice
+from repro.core.atp import (ATPContext, grad_sync, shard_slice,
+                            vma_rewrite_active)
 from repro.models import layers as L
 
 
@@ -104,11 +105,20 @@ def moe_block(ctx: ATPContext, cfg: ModelConfig, p, x):
     topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-9)
 
     # aux load-balance loss (Switch-style); tokens differ per TP rank here,
-    # so average the per-rank partials over the flat TP group
+    # so average the per-rank partials over the flat TP group.  Under
+    # replicated dispatch every rank holds the same aux, typed invariant
+    # over the axes its tokens were replicated on: cast it varying there
+    # first (the mean of identical copies is the value itself).  Builds
+    # without vma typing (ring boundary, quantized wire) have nothing to
+    # cast, and the cast's transpose would be a typed psum there
     me = jnp.mean(gates, axis=0)
     ce = jnp.mean(jax.nn.one_hot(topi[:, 0], e, dtype=jnp.float32), axis=0)
     aux = jnp.sum(me * ce) * e
     if ctx.tp_axes:
+        invariant = tuple(a for a in ctx.tp_axes
+                          if a not in jax.typeof(aux).vma)
+        if invariant and vma_rewrite_active(ctx):
+            aux = lax.pcast(aux, invariant, to="varying")
         aux = lax.psum(aux, ctx.tp_axes) / n
 
     # ---- capacity-bounded slot assignment

@@ -36,8 +36,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.atp import (ATPContext, atp_boundary, grad_sync,
-                            shard_slice, vma_rewrite_active)
+from repro.core.atp import (ATPContext, atp_boundary, gather_features,
+                            grad_sync, shard_slice, vma_rewrite_active)
 from repro.models import layers as L
 
 
@@ -291,8 +291,9 @@ def slstm_block(ctx: ATPContext, cfg: ModelConfig, p, x, state=None):
     h = cfg.d_model
     dh = h // nh
     xg = x
-    if ctx.ax2 is not None:  # need full h for the recurrent mixing
-        xg = lax.all_gather(x, ctx.ax2, axis=-1, tiled=True)
+    if ctx.ax2 is not None:  # full h for the recurrent mixing; a cached
+        # state is typed replicated over ax2, so with one the input must be
+        xg = gather_features(ctx, x, invariant=state is not None)
     # All sLSTM params are fully replicated (P()) while the block's
     # cotangent is rank-partial over the whole flat group (residual ct is
     # ax1-partial by the row-boundary convention and ax2-chunked by the
@@ -321,24 +322,17 @@ def slstm_block(ctx: ATPContext, cfg: ModelConfig, p, x, state=None):
         init = (z0, z0, z0)
     else:
         init = (state["c"], state["n"], state["h"])
-    # KNOWN LIMIT (EXPERIMENTS §Perf): the scan transpose still all-reduces
-    # d(r_gates) once per time step (16.8 MB x 4096/block); the production
-    # fix is a custom-vjp backward scan that accumulates dW locally and
-    # reduces once — left as the documented next iteration.
+    if vma_rewrite_active(ctx):
+        # cast r_gates varying, once, over every axis the recurrence varies
+        # on: left to the rewrite, the cast (and its transpose, the psum of
+        # d(r_gates)) lands inside the scan body, one all-reduce per time
+        # step.  Builds without the rewrite reduce it once in grad_sync.
+        vary = tuple(a for a in jax.typeof(pre).vma
+                     if a not in jax.typeof(r_gates).vma)
+        if vary:
+            r_gates = lax.pcast(r_gates, vary, to="varying")
     (c, n, hs), ys = lax.scan(step, init, jnp.moveaxis(pre, 1, 0))
     y = jnp.moveaxis(ys, 0, 1).reshape(b, x.shape[1], h)
-    # §Perf: cotangent barrier — psum the incoming (Partial-over-ax1)
-    # cotangent ONCE here, so the scan transpose runs fully invariant and
-    # does NOT emit a psum of d(r_gates) per TIME STEP (the baseline's
-    # dominant collective: 4096 all-reduces per sLSTM block).  vma builds
-    # only: there the rewrite would otherwise insert those per-step psums
-    # and the barrier's early reduction is absorbed by the invariant type.
-    # On legacy jax no psums are auto-inserted, so a mid-chain psum would
-    # BREAK the rank-partial cotangent convention (over-counting every
-    # grad upstream of it); the per-param grad_sync barriers handle the
-    # reduction instead, once per leaf.
-    if vma_rewrite_active(ctx):
-        y = _ct_psum_barrier(y, ctx.ax1)
     new_state = {"c": c, "n": n, "h": hs} if state is not None else None
 
     y = _rms_full(y, grad_sync(ctx, p["gn"], ctx.tp_axes),
@@ -355,29 +349,3 @@ def _rms_full(x, gamma, eps):
     inv = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return xf * inv * gamma
 
-
-import functools as _functools
-
-
-@_functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _ct_psum_barrier(y, axis):
-    """Identity forward; backward all-reduces the cotangent over `axis`.
-
-    Used where a replicated (invariant) computation zone meets a sharded
-    consumer: the consumer's cotangent is Partial over `axis`, and without
-    this barrier the lazy psum placement pushes the reduction inside the
-    upstream scan — one all-reduce per time step."""
-    return y
-
-
-def _barrier_fwd(y, axis):
-    return y, None
-
-
-def _barrier_bwd(axis, _, g):
-    if axis is None:
-        return (g,)
-    return (lax.psum(g, axis),)
-
-
-_ct_psum_barrier.defvjp(_barrier_fwd, _barrier_bwd)

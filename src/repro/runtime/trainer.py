@@ -7,7 +7,11 @@ step (XLA device errors).  The loop's contract:
   - every step is a pure function of (params, opt_state, batch(step))
   - batches are pure functions of (seed, step)   -> replay is exact
   - on failure: restore last committed checkpoint, rebuild the step
-    (possibly on a new mesh — elastic), continue from ckpt step
+    (possibly on a new mesh — elastic), continue from ckpt step.  An
+    out-of-memory or a program the compiler refused is not a device
+    fault: a retry would fail the same way, so it propagates at once.
+    Each built step is compiled before it first runs, so compile errors
+    are told apart by where they are raised, not by their text
   - stragglers: per-step wall time is tracked with an EMA; a step slower
     than `straggler_factor` x EMA fires the mitigation hook (on a real
     cluster: re-shard away from the slow host / preemptively checkpoint)
@@ -27,11 +31,34 @@ from repro.data.pipeline import TokenSource
 log = logging.getLogger("repro.trainer")
 
 
+def is_device_fault(e: Exception) -> bool:
+    """Whether a step that raised ``e`` while running is worth a restore
+    and a retry.  Out of memory (status ``RESOURCE_EXHAUSTED``) is not: the
+    same program fails the same way again.  Compile errors never reach
+    here: :func:`compile_ahead` raises them before the step runs."""
+    return not (isinstance(e, jax.errors.JaxRuntimeError)
+                and str(e).startswith("RESOURCE_EXHAUSTED"))
+
+
+def compile_ahead(step_fn, *args) -> None:
+    """Compile a jitted step for ``args`` before its first call, so an
+    error of the compiler surfaces here and not as a failed step.  The
+    jitted function reuses the executable on its next call with the same
+    argument types.  Plain Python steps (no ``lower``) are left alone."""
+    lower = getattr(step_fn, "lower", None)
+    if lower is not None:
+        lower(*args).compile()
+
+
+class _CompileError(Exception):
+    """Carries a compile error past the step's fault handler."""
+
+
 @dataclasses.dataclass
 class TrainerConfig:
+    ckpt_dir: str  # no default: a shared path would resume a stale run
     total_steps: int = 100
-    ckpt_dir: str = "/tmp/repro_ckpt"
-    ckpt_every: int = 50
+    ckpt_every: int = 50   # 0: never checkpoint (a run nothing resumes)
     keep_ckpts: int = 3
     max_failures: int = 3
     straggler_factor: float = 3.0
@@ -180,11 +207,18 @@ class Trainer:
         train_step = self.build_step()
         params, opt_state, start = self._restore_or_init()
         step = start
+        compiled = None     # the step compile_ahead last ran for
         while step < self.cfg.total_steps:
             try:
                 if fail_injector is not None:
                     fail_injector(step)
                 batch = self.put_batch(self.source.global_batch(step))
+                if compiled is not train_step:
+                    try:
+                        compile_ahead(train_step, params, opt_state, batch)
+                    except Exception as e:  # refused by the compiler
+                        raise _CompileError() from e
+                    compiled = train_step
                 t0 = self.time_fn()
                 params, opt_state, metrics = train_step(params, opt_state, batch)
                 loss = float(metrics["loss"])
@@ -203,7 +237,11 @@ class Trainer:
                     self.failures = 0
                 if step % self.cfg.log_every == 0:
                     log.info("step %d loss %.4f (%.3fs)", step, loss, dt)
-            except (RuntimeError, jax.errors.JaxRuntimeError) as e:
+            except _CompileError as e:
+                raise e.__cause__ from None
+            except RuntimeError as e:  # JaxRuntimeError included
+                if not is_device_fault(e):
+                    raise
                 self.failures += 1
                 self.total_failures += 1
                 log.error("step %d failed (%s); recovering (%d/%d)",
@@ -236,6 +274,7 @@ class Trainer:
                 params, opt_state, step = self._restore_or_init(shardings)
                 continue
             step += 1
-            if step % self.cfg.ckpt_every == 0 or step == self.cfg.total_steps:
+            every = self.cfg.ckpt_every
+            if every and (step % every == 0 or step == self.cfg.total_steps):
                 self._checkpoint(step, params, opt_state)
         return params, opt_state

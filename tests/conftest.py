@@ -1,7 +1,6 @@
-"""Shared fixtures.  Tests that need a multi-device mesh run in a subprocess
-spawned with XLA_FLAGS (device count is locked at first jax init), EXCEPT
-we set a modest 8-device count here for the whole test session — smoke
-tests and benches are told to expect it.
+"""Shared fixtures.  Every test runs in one process on 8 virtual CPU
+devices (XLA_FLAGS, set here before jax initializes; the device count is
+fixed at first init) — the multi-device tests build their meshes on them.
 """
 import os
 

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from repro.analysis.expect import (PlanConformanceError, check_conformance,
                                    expected_signature, lint_conformance)
@@ -23,7 +24,6 @@ from repro.configs.registry import get_config
 from repro.core import comm_matrix
 from repro.core.plan import ParallelPlan, plan_search
 from repro.core import atp
-from repro.core.compat import shard_map
 
 B, S = 4, 32
 

@@ -6,8 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-
-from repro.core.compat import shard_map
+from jax import shard_map
 
 from repro.configs.registry import ARCHS, get_config
 from repro.core.atp import make_context
@@ -34,7 +33,9 @@ def _batch(cfg, B=4, S=32, key=0):
     return b
 
 
-def _loss_on(topo, cfg, params, batch, remat=False):
+def _loss_on(topo, cfg, params, batch, remat=False, grad=False):
+    """Train loss of ``batch`` on ``topo``'s mesh (vma-checked); with
+    ``grad``, the gradients w.r.t. ``params`` instead, sharded like them."""
     mesh = topo.build(jax.devices()[: topo.size])
     ctx = make_context(topo)
     specs = lm.param_specs(cfg, ctx)
@@ -51,8 +52,9 @@ def _loss_on(topo, cfg, params, batch, remat=False):
     def f(p, b):
         return lm.train_loss(ctx, cfg, p, b, remat=remat)
 
-    g = shard_map(f, mesh=mesh, in_specs=(specs, bspec), out_specs=P(),
-                  check_vma=True)
+    g = shard_map(jax.grad(f) if grad else f, mesh=mesh,
+                  in_specs=(specs, bspec),
+                  out_specs=specs if grad else P(), check_vma=True)
     return jax.jit(g)(params, batch)
 
 
@@ -103,6 +105,32 @@ def test_distributed_matches_reference(devices8, arch):
     ref = _loss_on(TOPO1, cfg, params, batch)
     dist = _loss_on(TOPO8, cfg, params, batch, remat=True)
     np.testing.assert_allclose(float(dist), float(ref), rtol=5e-3)
+
+
+@pytest.mark.parametrize("arch", DIST_ARCHS)
+def test_distributed_grads_match_reference(devices8, arch):
+    """Every leaf's gradient on the (dp, d1, d2) = (2, 2, 2) mesh equals
+    the single-device gradient: the vma rewrite's reductions, grad_sync
+    and the sLSTM's hoisted recurrent-weight cast reduce each exactly
+    once (float32, so only the summation order differs)."""
+    cfg = get_config(arch).reduced()
+    if cfg.moe is not None:
+        # as above: no capacity drops in either layout.  The load-balance
+        # loss is a mean of per-rank products (each rank routes its own
+        # tokens), not the single device's product of means, so it is off
+        import dataclasses
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0,
+                                         aux_loss_weight=0.0))
+    params = lm.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    batch = _batch(cfg)
+    ref = _loss_on(TOPO1, cfg, params, batch, grad=True)
+    dist = _loss_on(TOPO8, cfg, params, batch, grad=True)
+    for (path, d), r in zip(jax.tree_util.tree_leaves_with_path(dist),
+                            jax.tree.leaves(ref)):
+        d, r = np.asarray(d), np.asarray(r)
+        rel = np.linalg.norm(d - r) / (np.linalg.norm(r) + 1e-30)
+        assert rel < 1e-3, (jax.tree_util.keystr(path), rel)
 
 
 @pytest.mark.parametrize("arch", ["llama3-8b", "musicgen-medium"])

@@ -5,8 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-
-from repro.core.compat import shard_map
+from jax import shard_map
 
 from repro.core.atp import (atp_linear, core_gather, core_scatter,
                             make_context, plan_core_sharding)
@@ -30,25 +29,17 @@ def _ref_loss(params, x):
     return jnp.sum((jax.nn.gelu(x @ A + bA) @ B + bB) ** 2)
 
 
-def _local_loss(ctx, params, x):
-    """Per-rank PARTIAL of the dense loss: z is replicated over tp1 (post-f4
-    psum), so divide by d1 so the partials sum to the global loss over every
-    mesh axis.  Differentiating the partial keeps grads exact under jax's
-    per-rank cotangent convention (grad-through-psum is only exact under the
-    0.6 vma system; 0.4.x transposes psum to psum)."""
+def _global_loss(ctx, params, x):
+    """The dense loss inside shard_map.  z is invariant over tp1 (post-f4
+    psum) and varies over (data, tp2), so the psum runs over exactly the
+    axes it varies on.  Under vma typing, jax.grad through that psum gives
+    every param its full gradient: the transpose of each implicit pvary
+    (a replicated param meeting varying data) is the gradient all-reduce
+    over that param's replicated axes."""
     A, bA, B, bB = params
     y = jax.nn.gelu(atp_linear(ctx, x, A, bA, kind="col"))
     z = atp_linear(ctx, y, B, bB, kind="row")
-    return jnp.sum(z ** 2) / ctx.d1
-
-
-def _grad_psums(grads):
-    """Conjugate reductions over each param's replicated mesh axes."""
-    gA, gbA, gB, gbB = grads
-    return (jax.lax.psum(gA, ("data",)),
-            jax.lax.psum(gbA, ("data", "tp2")),
-            jax.lax.psum(gB, ("data",)),
-            jax.lax.psum(gbB, ("data", "tp1")))
+    return jax.lax.psum(jnp.sum(z ** 2), ("data", "tp2"))
 
 
 @pytest.mark.parametrize("chunks", [1, 2, 4])
@@ -57,10 +48,7 @@ def test_mlp_forward_and_grads_match_dense(devices8, chunks):
     ctx = make_context(TOPO, chunks=chunks)
 
     def step(params, x):
-        loss, grads = jax.value_and_grad(
-            lambda p: _local_loss(ctx, p, x))(params)
-        loss = jax.lax.psum(loss, ("data", "tp1", "tp2"))
-        return loss, _grad_psums(grads)
+        return jax.value_and_grad(lambda p: _global_loss(ctx, p, x))(params)
 
     in_specs = ((P("tp2", "tp1"), P("tp1"), P("tp1", "tp2"), P("tp2")),
                 P("data", "tp2"))
@@ -78,13 +66,14 @@ def test_mlp_forward_and_grads_match_dense(devices8, chunks):
 def test_eq2_collective_count(devices8):
     """The lowered HLO of one MLP block contains exactly the paper's two
     forward boundaries (f3 psum(ax2), f4 psum(ax1)) + their two backward
-    conjugates: 4 all-reduces of activation tensors, plus the explicit
-    DP/replication grad reductions (up to 4 more, partially fused by XLA)."""
+    conjugates: 4 all-reduces of activation tensors, plus the
+    DP/replication grad reductions (up to 4 more, partially fused by XLA)
+    and the loss psum."""
     mesh, params, X = _setup()
     ctx = make_context(TOPO)
 
     def grads(params, x):
-        return _grad_psums(jax.grad(lambda p: _local_loss(ctx, p, x))(params))
+        return jax.grad(lambda p: _global_loss(ctx, p, x))(params)
 
     in_specs = ((P("tp2", "tp1"), P("tp1"), P("tp1", "tp2"), P("tp2")),
                 P("data", "tp2"))
@@ -92,7 +81,7 @@ def test_eq2_collective_count(devices8):
                           out_specs=in_specs[0], check_vma=True))
     hlo = f.lower(params, X).compile().as_text()
     n_ar = hlo.count(" all-reduce(") + hlo.count(" all-reduce-start(")
-    # f3 fwd, f4 fwd, f4 bwd, f3 bwd + explicit grad psums
+    # f3 fwd, f4 fwd, f4 bwd, f3 bwd + grad reductions
     assert 4 <= n_ar <= 9, f"expected the Eq.2 schedule, got {n_ar} all-reduces"
 
 

@@ -7,8 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-
-from repro.core.compat import shard_map
+from jax import shard_map
 
 from repro.configs.registry import get_config
 from repro.core.atp import make_context

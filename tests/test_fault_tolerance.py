@@ -3,12 +3,12 @@ recovery, straggler watchdog (deliverable: large-scale runnability)."""
 import os
 
 import jax
-from repro.core import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.checkpoint import manager as ckpt
+from repro.core.mesh import make_mesh
 from repro.data.pipeline import DataConfig, TokenSource
 from repro.runtime.trainer import StragglerWatchdog, Trainer, TrainerConfig
 
@@ -76,11 +76,11 @@ class TestCheckpoint:
     def test_elastic_reshard_on_restore(self, tmp_path, devices8):
         """Save under one mesh, restore under a different one."""
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh4 = compat.make_mesh((4,), ("data",))
+        mesh4 = make_mesh((4,), ("data",))
         x = jax.device_put(jnp.arange(16.0),
                            NamedSharding(mesh4, P("data")))
         ckpt.save(str(tmp_path), 1, {"x": x})
-        mesh8 = compat.make_mesh((8,), ("data",))
+        mesh8 = make_mesh((8,), ("data",))
         tgt = NamedSharding(mesh8, P("data"))
         out, _ = ckpt.restore(str(tmp_path), {"x": jnp.zeros(16)},
                               shardings={"x": tgt})
@@ -94,7 +94,7 @@ class TestCheckpoint:
         from jax.sharding import NamedSharding, PartitionSpec as P
         ckpt.save(str(tmp_path), 1, {"x": jnp.arange(16.0),  # f32 on disk
                                      "y": jnp.arange(8.0)})
-        mesh = compat.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         tgt = NamedSharding(mesh, P("data"))
         tmpl = {"x": jnp.zeros(16, jnp.bfloat16),
                 "y": jnp.zeros(8, jnp.bfloat16)}
@@ -113,7 +113,7 @@ class TestCheckpoint:
         from jax.sharding import NamedSharding, PartitionSpec as P
         x = jnp.arange(16.0, dtype=jnp.bfloat16) / 3
         ckpt.save(str(tmp_path), 1, {"x": x, "y": x})
-        mesh = compat.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         tgt = NamedSharding(mesh, P("data"))
         tmpl = {"x": jnp.zeros(16, jnp.bfloat16),
                 "y": jnp.zeros(16, jnp.bfloat16)}
@@ -140,6 +140,33 @@ class _Clock:
     def __call__(self):
         self.t += self.step_cost / 2
         return self.t
+
+
+def _fail_at(step, exc):
+    """A fail_injector that raises ``exc`` once, at ``step``."""
+    fired = []
+
+    def injector(s):
+        if s == step and not fired:
+            fired.append(s)
+            raise exc
+    return injector
+
+
+class _Refused:
+    """A jitted-step stand-in whose compile fails as the compiler's does."""
+
+    def __init__(self, msg):
+        self.msg = msg
+
+    def lower(self, *args):
+        return self
+
+    def compile(self):
+        raise jax.errors.JaxRuntimeError(self.msg)
+
+    def __call__(self, *args):
+        raise AssertionError("a step the compiler refused was run")
 
 
 class TestTrainer:
@@ -180,6 +207,72 @@ class TestTrainer:
         assert tr.total_failures == 1
         assert tr.failures == 0  # consecutive counter decayed on recovery
         assert float(params["w"]) == 8.0  # deterministic replay -> same result
+
+    _OOM = ("RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
+            "17179869184 bytes.")
+    _REFUSED = "INTERNAL: Mosaic failed to compile TPU kernel: block shape"
+
+    @pytest.mark.parametrize("msg", [_OOM, _REFUSED])
+    def test_oom_and_compile_errors_propagate_without_restore(
+            self, tmp_path, msg):
+        """An out-of-memory while the step runs, or a program the compiler
+        refuses, is not a device fault: the first one raises, with no
+        restore and no failure counted."""
+        tr = self._mk(tmp_path)
+        restores = []
+        orig = tr._restore_or_init
+        tr._restore_or_init = lambda *a: restores.append(a) or orig(*a)
+        if msg == self._OOM:
+            def injector(step):
+                if step == 3:
+                    raise jax.errors.JaxRuntimeError(msg)
+        else:   # a rebuilt step the compiler refuses
+            injector = _fail_at(3, RuntimeError("transient device fault"))
+            plain = tr.build_step
+            builds = []
+
+            def build_step():
+                builds.append(1)
+                return plain() if len(builds) == 1 else _Refused(msg)
+            tr.build_step = build_step
+        with pytest.raises(jax.errors.JaxRuntimeError, match=msg[:12]):
+            tr.run(fail_injector=injector)
+        assert len(restores) == 1 + (msg == self._REFUSED)
+        assert tr.total_failures == (msg == self._REFUSED)
+        assert [h["step"] for h in tr.history] == [0, 1, 2]
+
+    def test_invalid_argument_while_running_is_retried(self, tmp_path):
+        """Only RESOURCE_EXHAUSTED is final among errors raised while a
+        step runs: INVALID_ARGUMENT (what the runtime reports for a step
+        that touches a buffer a failed step had donated) is restored and
+        retried like any device fault."""
+        tr = self._mk(tmp_path)
+        tr.run(fail_injector=_fail_at(5, jax.errors.JaxRuntimeError(
+            "INVALID_ARGUMENT: Buffer has been deleted or donated.")))
+        assert tr.total_failures == 1
+        assert [h["step"] for h in tr.history][-3:] == [5, 6, 7]
+
+    def test_jitted_step_is_compiled_once_before_it_runs(self, tmp_path):
+        """compile_ahead's executable is the one the jitted step runs: the
+        loop compiles each built step once, and the call reuses it."""
+        from jax._src import compiler
+
+        tr = self._mk(tmp_path, total=3, ckpt_every=0)
+        plain = tr.build_step
+        tr.build_step = lambda: jax.jit(plain())
+        seen = []
+        orig = compiler.backend_compile_and_load
+
+        def counting(*a, **k):
+            seen.append(1)
+            return orig(*a, **k)
+        compiler.backend_compile_and_load = counting
+        try:
+            params, _ = tr.run()
+        finally:
+            compiler.backend_compile_and_load = orig
+        assert float(params["w"]) == 3.0
+        assert len(seen) == 1
 
     def test_failure_counter_decays_after_recovery(self, tmp_path):
         """Sporadic transient faults over a long run must not accumulate
@@ -231,7 +324,7 @@ class TestTrainer:
         """_restore_or_init passes the current plan's shardings into
         ckpt.restore, so resumed state lands sharded, not replicated."""
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = compat.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         tgt = NamedSharding(mesh, P("data"))
         ckpt.save(str(tmp_path), 3, ({"w": jnp.arange(8.0)}, {}))
         src = TokenSource(DataConfig(vocab_size=10, seq_len=4, global_batch=2))
@@ -411,7 +504,7 @@ class TestZero1CheckpointLayout:
         """The canonical view of trained zero1 state equals the plain-mode
         state for the same trajectory (moments preserved exactly where the
         parity test pins the updates)."""
-        from repro.core.compat import shard_map
+        from jax import shard_map
         topo = MeshTopo((("data", 4), ("tp1", 2)))
         mesh = topo.build(jax.devices()[:8])
         ctx = make_context(topo)

@@ -4,9 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.core import compat
-from repro.core.compat import shard_map
+from repro.core.mesh import make_mesh
 
 from repro.launch.hlo_analysis import (collective_bytes, full_analysis,
                                        shape_bytes)
@@ -58,7 +58,7 @@ def test_async_pairs_counted_once_uniformly():
 
 
 def _compile(f, in_specs, out_specs, *args, mesh=None):
-    mesh = mesh or compat.make_mesh((4,), ("m",))
+    mesh = mesh or make_mesh((4,), ("m",))
     return jax.jit(shard_map(f, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs,
                              check_vma=False)).lower(*args).compile().as_text()

@@ -11,11 +11,11 @@ import numpy as np
 import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from repro.core import comm_matrix as cm
 from repro.core import overlap
 from repro.core.atp import atp_linear, make_context
-from repro.core.compat import shard_map
 from repro.core.cost_model import LayerCommProfile, t_comm_overlap
 from repro.core.mesh import MeshTopo
 from repro.core.search import search_strategy, search_strategy_overlap

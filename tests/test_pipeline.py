@@ -3,16 +3,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
-from repro.core import compat
-from repro.core.compat import shard_map
-
+from repro.core.mesh import make_mesh
 from repro.core.pipeline import gpipe_forward, gpipe_loss
 
 
 def _setup(S=4, M=8):
-    mesh = compat.make_mesh((S,), ("pod",))
+    mesh = make_mesh((S,), ("pod",))
     # S stages, each one matmul + tanh; stacked stage params [S, d, d]
     d = 16
     Ws = jax.random.normal(jax.random.PRNGKey(0), (S, d, d)) * (0.5 / d ** 0.5)
@@ -45,7 +45,7 @@ def test_gpipe_forward_matches_dense(devices8):
     def f2(w, x):
         outs = gpipe_forward(_stage, w[0], x, "pod")
         # broadcast the last stage's result to everyone for checking
-        ok = (jax.lax.axis_index("pod") == compat.axis_size("pod") - 1)
+        ok = (jax.lax.axis_index("pod") == lax.axis_size("pod") - 1)
         return jax.lax.psum(jnp.where(ok, outs, 0.0), "pod")
 
     g2 = jax.jit(shard_map(f2, mesh=mesh, in_specs=(P("pod"), P()),
@@ -84,7 +84,7 @@ def test_gpipe_bubble_cost_is_s_minus_1(devices8):
 
     def f(w, x):
         outs = gpipe_forward(counting_stage, w[0], x, "pod")
-        ok = (jax.lax.axis_index("pod") == compat.axis_size("pod") - 1)
+        ok = (jax.lax.axis_index("pod") == lax.axis_size("pod") - 1)
         return jax.lax.psum(jnp.where(ok, outs, 0.0), "pod")
 
     hlo = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("pod"), P()),

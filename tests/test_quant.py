@@ -11,12 +11,12 @@ import numpy as np
 import pytest
 from jax import lax
 from jax.sharding import PartitionSpec as P
+from jax import shard_map
 
 from repro.configs.registry import get_config
 from repro.core import comm_matrix, overlap
 from repro.core.atp import make_context
 from repro.core.calibrate import CalibEntry, CalibrationTable, calibrate_mesh
-from repro.core.compat import shard_map
 from repro.core.cost_model import LayerCommProfile, wire_bytes_per_elem
 from repro.core.mesh import MeshTopo, atp_topo
 from repro.core.plan import PLAN_FORMAT_VERSION, ParallelPlan, plan_search
@@ -142,6 +142,74 @@ def test_quant_overlap_matmul_ar_parity(devices8, wd):
     assert np.all(np.isfinite(ga))
     grel = np.max(np.abs(ga - gr)) / (np.max(np.abs(gr)) + 1e-12)
     assert grel < 0.1, grel
+
+
+# A quantized-wire train build runs check_vma=False, as a ring build does:
+# the backward of its custom-VJP collectives reduces a rank-partial
+# cotangent on the same quantized wire, and vma typing makes that
+# cotangent invariant, leaving the wire nothing to reduce.  Without the
+# rewrite each of the n = d1*d2 ranks holds the full gradient of the
+# replicated loss, so the grads are n x the vma build's (the "x n
+# convention", docs/analysis.md).  Per-leaf relative error of the scaled
+# grads vs the bf16 wire, measured on reduced llama3 at (2,2): 0.068
+# (int8), 0.095 (fp8); the bounds leave ~2x headroom and stay far under
+# the 1.0 a double-counted reduction gives.
+_GRAD_REL = {"int8": 0.15, "fp8": 0.2}
+
+
+@pytest.mark.parametrize("wd", ["int8", "fp8"])
+def test_quant_wire_train_step_grads_and_replicas(devices8, wd):
+    """A whole quantized-wire train step on a 2x2 mesh: its gradients are
+    n x the bf16-wire build's within the wire grid's error, and one AdamW
+    update through ``build_train_step`` leaves every replica of every
+    leaf bitwise identical (zero drift)."""
+    from repro.launch.steps import _check_vma, batch_pspecs, \
+        build_train_step, resolve_ctx
+
+    cfg = dataclasses.replace(get_config("llama3-8b").reduced(),
+                              dtype="float32")
+    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    tok = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
+                             cfg.vocab_size)
+    batch = {"tokens": tok, "labels": jnp.roll(tok, -1, axis=1)}
+
+    def grads(plan):
+        ctx = resolve_ctx(None, plan)
+        ps = lm.param_specs(cfg, ctx)
+        fn = shard_map(
+            jax.grad(lambda p, b: lm.train_loss(ctx, cfg, p, b,
+                                                remat=False)),
+            mesh=ctx.topo.build(jax.devices()[:4]),
+            in_specs=(ps, batch_pspecs(cfg, ctx.topo, "train")),
+            out_specs=ps, check_vma=_check_vma(ctx))
+        return jax.tree.map(np.asarray, jax.jit(fn)(params, batch)), ctx
+
+    quant = ParallelPlan(d1=2, d2=2, dp=1, wire_dtype=wd)
+    ref, rctx = grads(ParallelPlan(d1=2, d2=2, dp=1))
+    got, qctx = grads(quant)
+    assert _check_vma(rctx) and not _check_vma(qctx)
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(ref)):
+        rel = np.linalg.norm(g / qctx.tp - r) / np.linalg.norm(r)
+        assert rel <= _GRAD_REL[wd], (jax.tree_util.keystr(path), rel)
+
+    step, info = build_train_step(cfg, plan=quant)
+    opt = adamw.init_opt_state(params, info.pspecs, info.ctx, "zero1")
+    new, _, metrics = step(
+        jax.device_put(params, info.sharding(info.pspecs)),
+        jax.device_put(opt, info.sharding(info.ospecs)),
+        jax.device_put(batch, info.sharding(info.bspecs)))
+    assert np.isfinite(float(metrics["loss"]))
+    for path, leaf in jax.tree_util.tree_leaves_with_path(new):
+        by_index = {}
+        for shard in leaf.addressable_shards:
+            by_index.setdefault(str(shard.index), []).append(
+                np.asarray(shard.data))
+        for copies in by_index.values():
+            for c in copies[1:]:
+                np.testing.assert_array_equal(
+                    c, copies[0], err_msg=jax.tree_util.keystr(path))
+        assert len(leaf.addressable_shards) == 4
 
 
 # ---------------------------------------------------------------------------

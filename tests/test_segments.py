@@ -351,7 +351,7 @@ def test_embeds_entry_respects_masked_first_segment(devices8):
     supplied embeds — the entry follows the first segment's masked view."""
     import numpy as np
 
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from repro.core.mesh import MeshTopo
     from repro.models import lm
 
