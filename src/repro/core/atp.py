@@ -37,9 +37,21 @@ Beyond-paper boundary modes (see docs/overlap.md):
     layer_norm `gather_seq=True`).  Activation memory between blocks drops
     by d1.  Eq. 2's row term keeps its volume across fwd+bwd but every
     boundary op halves, which is what the overlap engine pipelines against.
+
+Name scopes (:func:`atp_scope`): each collective site runs under
+``atp:<site>[<axes>]``, which reaches every device op as its name stack:
+``atp:col[..]`` / ``atp:row[..]`` for the linear boundaries in every form
+(AD keeps the scope on the backward ops, under ``transpose(..)`` of the
+enclosing scope), ``atp:norm[..]`` for the feature-sharded norms,
+``atp:heads[..]`` for the attention core's head split and output gather,
+``atp:grad_sync[..]`` for the explicit gradient reductions.  ``-`` stands
+for no axis: a column boundary on a mesh with d2 = 1 still reduces its
+backward over ax1.  Where ``ctx.tp`` is 1 none of these collectives runs
+and no scope opens.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -399,6 +411,16 @@ def make_context(
 # Boundary collectives (f1..f4).
 # ---------------------------------------------------------------------------
 
+def atp_scope(ctx, site: str, axes):
+    """``jax.named_scope("atp:<site>[<axes>]")`` (``-`` for no axis) on a
+    tensor-parallel mesh; no scope where ``ctx.tp`` is 1 (no collective
+    runs)."""
+    if ctx.tp == 1:
+        return contextlib.nullcontext()
+    names = (axes if isinstance(axes, str) else "+".join(axes or ())) or "-"
+    return jax.named_scope(f"atp:{site}[{names}]")
+
+
 def atp_boundary(x, axis: str | None):
     """Resolve a Partial(axis) activation: all-reduce over one mesh dim.
 
@@ -446,7 +468,8 @@ def grad_sync(ctx, x, axes):
     """
     if not axes or vma_rewrite_active(ctx):
         return x
-    return _grad_sync(x, axes if isinstance(axes, str) else tuple(axes))
+    with atp_scope(ctx, "grad_sync", axes):
+        return _grad_sync(x, axes if isinstance(axes, str) else tuple(axes))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -576,8 +599,14 @@ def atp_linear(
 
     Bias is sharded like the GEMM output dim and applied in the boundary
     epilogue (psum is linear; keeps the bias gradient exact and local).
+    The GEMM and its boundary run under ``atp:<kind>[<axis>]``.
     """
     axis = ctx.ax2 if kind == "col" else ctx.ax1
+    with atp_scope(ctx, kind, axis):
+        return _linear(ctx, x, w, b, kind, axis, chunked)
+
+
+def _linear(ctx: ATPContext, x, w, b, kind: str, axis, chunked: bool):
     quant = ctx.wire_dtype != "bf16" and axis is not None
     if (ctx.seq_parallel and kind == "row" and axis is not None
             and x.ndim >= 3):

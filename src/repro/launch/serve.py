@@ -33,14 +33,15 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.core.mesh import atp_topo
 from repro.core.plan import ParallelPlan
-from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.compile_cache import dispatch_span, enable_compile_cache
 from repro.launch.steps import (build_decode_step, build_paged_step,
                                 init_params_sharded, resolve_ctx)
 from repro.models import lm
 from repro.models.paging import PagedConfig
-from repro.runtime.server import Request, Server, ServerConfig
+from repro.runtime.server import CALL_ARGS, Request, Server, ServerConfig
 
 log = logging.getLogger("repro.serve")
+span = jax.profiler.TraceAnnotation
 
 
 def serve(cfg, topo, params, prompts, max_new: int, max_seq: int,
@@ -161,24 +162,19 @@ def _build_paged_step_fn(cfg, scfg: ServerConfig, params, topo,
             slots=scfg.batch_slots if recurrent else None)
         return jax.device_put(caches, info.sharding(cache_specs))
 
-    if recurrent:
-        def step_fn(tokens, start, table, slot, caches):
-            toks, caches = step(params, jnp.asarray(tokens),
-                                jnp.asarray(start), jnp.asarray(table),
-                                jnp.asarray(slot), caches)
-            return np.asarray(toks), caches
-    elif scfg.speculate:
-        def step_fn(tokens, start, table, caches):
-            toks, drafts, caches = step(params, jnp.asarray(tokens),
-                                        jnp.asarray(start),
-                                        jnp.asarray(table), caches)
-            return np.asarray(toks), np.asarray(drafts), caches
-    else:
-        def step_fn(tokens, start, table, caches):
-            toks, caches = step(params, jnp.asarray(tokens),
-                                jnp.asarray(start), jnp.asarray(table),
-                                caches)
-            return np.asarray(toks), caches
+    def step_fn(*args):
+        """(tokens, start, table[, slot], caches) -> (tokens[, drafts],
+        caches): host arrays in, host tokens out, one profiler span per
+        part of the call."""
+        *host, caches = args
+        tags = CALL_ARGS.get()
+        with span("serve.call.upload", **tags):
+            dev = [jnp.asarray(a) for a in host]
+        with dispatch_span("serve.call.dispatch", "serve.call.recompile",
+                           **tags):
+            *toks, caches = step(params, *dev, caches)
+        with span("serve.call.read", **tags):
+            return (*(np.asarray(t) for t in toks), caches)
 
     return step_fn, init_caches, info
 

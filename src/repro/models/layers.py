@@ -14,7 +14,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.atp import (ATPContext, atp_boundary, grad_sync,
+from repro.core.atp import (ATPContext, atp_boundary, atp_scope, grad_sync,
                             seq_gather, shard_slice)
 
 # ---------------------------------------------------------------------------
@@ -71,29 +71,32 @@ def rms_norm(ctx: ATPContext, x, gamma, eps: float = 1e-6,
     # ax2-sharded scale, but its cotangent is ax1-PARTIAL: the norm output
     # feeds a column boundary whose out dim is ax1-sharded, so each rank's
     # scale grad sums only its columns (and, under sp, its tokens).
-    gamma = grad_sync(ctx, gamma, ctx.ax1)
-    xf = x.astype(jnp.float32)
-    ss = jnp.sum(xf * xf, axis=-1, keepdims=True)
-    ss = atp_boundary(ss, ctx.ax2)  # full-feature sum of squares
-    d = x.shape[-1] * ctx.d2
-    inv = lax.rsqrt(ss / d + eps)
-    g = (1.0 + gamma) if plus_one else gamma
-    out = (xf * inv * g).astype(x.dtype)
-    return seq_gather(ctx, out, dim=out.ndim - 2) if gather_seq else out
+    with atp_scope(ctx, "norm", ctx.ax2):
+        gamma = grad_sync(ctx, gamma, ctx.ax1)
+        xf = x.astype(jnp.float32)
+        ss = jnp.sum(xf * xf, axis=-1, keepdims=True)
+        ss = atp_boundary(ss, ctx.ax2)  # full-feature sum of squares
+        d = x.shape[-1] * ctx.d2
+        inv = lax.rsqrt(ss / d + eps)
+        g = (1.0 + gamma) if plus_one else gamma
+        out = (xf * inv * g).astype(x.dtype)
+        return seq_gather(ctx, out, dim=out.ndim - 2) if gather_seq else out
 
 
 def layer_norm(ctx: ATPContext, x, gamma, beta, eps: float = 1e-5,
                gather_seq: bool = False):
-    gamma = grad_sync(ctx, gamma, ctx.ax1)
-    beta = grad_sync(ctx, beta, ctx.ax1)
-    xf = x.astype(jnp.float32)
-    d = x.shape[-1] * ctx.d2
-    s = atp_boundary(jnp.sum(xf, axis=-1, keepdims=True), ctx.ax2)
-    mu = s / d
-    ss = atp_boundary(jnp.sum((xf - mu) ** 2, axis=-1, keepdims=True), ctx.ax2)
-    inv = lax.rsqrt(ss / d + eps)
-    out = ((xf - mu) * inv * gamma + beta).astype(x.dtype)
-    return seq_gather(ctx, out, dim=out.ndim - 2) if gather_seq else out
+    with atp_scope(ctx, "norm", ctx.ax2):
+        gamma = grad_sync(ctx, gamma, ctx.ax1)
+        beta = grad_sync(ctx, beta, ctx.ax1)
+        xf = x.astype(jnp.float32)
+        d = x.shape[-1] * ctx.d2
+        s = atp_boundary(jnp.sum(xf, axis=-1, keepdims=True), ctx.ax2)
+        mu = s / d
+        ss = atp_boundary(jnp.sum((xf - mu) ** 2, axis=-1, keepdims=True),
+                          ctx.ax2)
+        inv = lax.rsqrt(ss / d + eps)
+        out = ((xf - mu) * inv * gamma + beta).astype(x.dtype)
+        return seq_gather(ctx, out, dim=out.ndim - 2) if gather_seq else out
 
 
 def norm(ctx: ATPContext, cfg: ModelConfig, x, p, gather_seq: bool = False):
@@ -291,36 +294,39 @@ def attention_core(
     kv_len=None,                  # decode: valid cache length (scalar or [b])
     window: int = 0,              # sliding window (0 = global)
 ):
-    b, sq, hq, hd = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
-    rep = hq // hkv
-    if rep > 1:
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    scale = 1.0 / math.sqrt(cfg.hd if cfg.mla is None else q.shape[-1])
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    if cfg.attn_softcap:
-        scores = cfg.attn_softcap * jnp.tanh(scores / cfg.attn_softcap)
-    # per-slot offsets/lengths (paged serving) build a [b, 1, sq, skv]
-    # mask; the scalar path keeps its original [1, 1, sq, skv] shape
-    q_off = jnp.asarray(q_offset)
-    per_slot = q_off.ndim > 0
-    if per_slot:
-        qpos = q_off[:, None, None] + jnp.arange(sq)[None, :, None]
-        kpos = jnp.arange(skv)[None, None, :]
-    else:
-        qpos = q_off + jnp.arange(sq)[:, None]
-        kpos = jnp.arange(skv)[None, :]
-    mask = kpos <= qpos
-    # window may be a traced per-layer scalar (scanned); 0 means global
-    win = jnp.asarray(window, jnp.int32)
-    win_eff = jnp.where(win > 0, win, jnp.int32(2**30))
-    mask &= kpos > qpos - win_eff
-    if kv_len is not None:
-        kl = jnp.asarray(kv_len)
-        mask = mask & (kpos < (kl[:, None, None] if kl.ndim else kl))
-    # [b, sq, skv] -> [b, 1, sq, skv]; scalar path [sq, skv] -> [1, 1, ...]
-    mask = mask[:, None] if mask.ndim == 3 else mask[None, None]
-    scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    """The scores, mask, softmax and ``p @ v`` of attention, under the
+    ``attn:core`` name scope (what a fused attention kernel replaces)."""
+    with jax.named_scope("attn:core"):
+        b, sq, hq, hd = q.shape
+        skv, hkv = k.shape[1], k.shape[2]
+        rep = hq // hkv
+        if rep > 1:
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
+        scale = 1.0 / math.sqrt(cfg.hd if cfg.mla is None else q.shape[-1])
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+        if cfg.attn_softcap:
+            scores = cfg.attn_softcap * jnp.tanh(scores / cfg.attn_softcap)
+        # per-slot offsets/lengths (paged serving) build a [b, 1, sq, skv]
+        # mask; the scalar path keeps its original [1, 1, sq, skv] shape
+        q_off = jnp.asarray(q_offset)
+        per_slot = q_off.ndim > 0
+        if per_slot:
+            qpos = q_off[:, None, None] + jnp.arange(sq)[None, :, None]
+            kpos = jnp.arange(skv)[None, None, :]
+        else:
+            qpos = q_off + jnp.arange(sq)[:, None]
+            kpos = jnp.arange(skv)[None, :]
+        mask = kpos <= qpos
+        # window may be a traced per-layer scalar (scanned); 0 means global
+        win = jnp.asarray(window, jnp.int32)
+        win_eff = jnp.where(win > 0, win, jnp.int32(2**30))
+        mask &= kpos > qpos - win_eff
+        if kv_len is not None:
+            kl = jnp.asarray(kv_len)
+            mask = mask & (kpos < (kl[:, None, None] if kl.ndim else kl))
+        # [b, sq, skv] -> [b, 1, sq, skv]; scalar path [sq, skv] -> [1, 1, ...]
+        mask = mask[:, None] if mask.ndim == 3 else mask[None, None]
+        scores = jnp.where(mask, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)

@@ -17,8 +17,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.core.atp import (ATPContext, atp_boundary, atp_linear, grad_sync,
-                            shard_slice)
+from repro.core.atp import (ATPContext, atp_boundary, atp_linear, atp_scope,
+                            grad_sync, shard_slice)
 from repro.models import layers as L
 from repro.models import paging
 
@@ -116,23 +116,30 @@ def attn_block(
     """Returns (attn output [b, s, h/d2], new_cache)."""
     # f1: column-first q/k/v projections, one fused boundary psum(ax2)
     parts = jnp.concatenate([p["wq"], p["wk"], p["wv"]], axis=1)
-    qkv = atp_boundary(jnp.einsum("...k,kn->...n", x, parts), ctx.ax2)
+    with atp_scope(ctx, "col", ctx.ax2):
+        qkv = atp_boundary(jnp.einsum("...k,kn->...n", x, parts), ctx.ax2)
     d1 = ctx.d1
     qd, kvd = cfg.q_dim // d1, cfg.kv_dim // d1
     qp, kp, vp = (qkv[..., :qd], qkv[..., qd:qd + kvd], qkv[..., qd + kvd:])
-    if cfg.qkv_bias:
-        # bias shards are ax2-replicated (P(ax1)) but consumed by the
-        # rank-local head/seq split below, so their cotangent is ax2-partial
-        qp = qp + grad_sync(ctx, p["bq"], ctx.ax2)
-        kp = kp + grad_sync(ctx, p["bk"], ctx.ax2)
-        vp = vp + grad_sync(ctx, p["bv"], ctx.ax2)
+    # the head split, and the gradient reductions of the replicated bias
+    # and qk-norm gains it makes rank-partial
+    with atp_scope(ctx, "heads", ctx.tp_axes):
+        if cfg.qkv_bias:
+            # bias shards are ax2-replicated (P(ax1)) but consumed by the
+            # rank-local head/seq split below, so their cotangent is
+            # ax2-partial
+            qp = qp + grad_sync(ctx, p["bq"], ctx.ax2)
+            kp = kp + grad_sync(ctx, p["bk"], ctx.ax2)
+            vp = vp + grad_sync(ctx, p["bv"], ctx.ax2)
 
-    q, k, v, bid, rid = L.split_qkv_heads(ctx, cfg, qp, kp, vp, plan)
+        q, k, v, bid, rid = L.split_qkv_heads(ctx, cfg, qp, kp, vp, plan)
 
-    if cfg.qk_norm:
-        # per-head norm gains see only the rank-local heads' cotangent
-        q = _qk_norm(q, grad_sync(ctx, p["q_norm"], ctx.tp_axes), cfg.norm_eps)
-        k = _qk_norm(k, grad_sync(ctx, p["k_norm"], ctx.tp_axes), cfg.norm_eps)
+        if cfg.qk_norm:
+            # per-head norm gains see only the rank-local heads' cotangent
+            q = _qk_norm(q, grad_sync(ctx, p["q_norm"], ctx.tp_axes),
+                         cfg.norm_eps)
+            k = _qk_norm(k, grad_sync(ctx, p["k_norm"], ctx.tp_axes),
+                         cfg.norm_eps)
 
     decode = cache is not None
     sq_offset = 0
@@ -184,7 +191,8 @@ def attn_block(
     else:
         o = L.attention_core(cfg, q, k, v, q_offset=sq_offset, window=layer_window)
 
-    o = L.core_output_gather(ctx, cfg, o, plan, seq_split=not decode)
+    with atp_scope(ctx, "heads", ctx.tp_axes):
+        o = L.core_output_gather(ctx, cfg, o, plan, seq_split=not decode)
     # f2: row-first output projection, boundary psum(ax1)
     out = atp_linear(ctx, o, p["wo"], kind="row")
     return out, new_cache
@@ -228,17 +236,20 @@ def dense_block(
     """With ``ctx.seq_parallel`` the residual stream x is seq-sharded over
     ax1: the entry norms fold the all-gather to full sequence, and the
     row-first output projections (f2/f4) psum_scatter back — post-block
-    norms and residual adds stay in the seq-sharded domain."""
+    norms and residual adds stay in the seq-sharded domain.  The two
+    sublayers run under the ``block:attn`` and ``block:mlp`` scopes."""
     sp = ctx.seq_parallel and cache is None
-    h = L.norm(ctx, cfg, x, p["ln_attn"], gather_seq=sp)
-    a, new_cache = attn_block(ctx, cfg, p["attn"], h, positions, plan,
-                              layer_window=layer_window, cache=cache,
-                              paged=paged)
-    if cfg.post_block_norms:
-        a = L.norm(ctx, cfg, a, p["ln_post_attn"])
+    with jax.named_scope("block:attn"):
+        h = L.norm(ctx, cfg, x, p["ln_attn"], gather_seq=sp)
+        a, new_cache = attn_block(ctx, cfg, p["attn"], h, positions, plan,
+                                  layer_window=layer_window, cache=cache,
+                                  paged=paged)
+        if cfg.post_block_norms:
+            a = L.norm(ctx, cfg, a, p["ln_post_attn"])
     x = x + a
-    h = L.norm(ctx, cfg, x, p["ln_mlp"], gather_seq=sp)
-    m = mlp_block(ctx, cfg, p["mlp"], h)
-    if cfg.post_block_norms:
-        m = L.norm(ctx, cfg, m, p["ln_post_mlp"])
+    with jax.named_scope("block:mlp"):
+        h = L.norm(ctx, cfg, x, p["ln_mlp"], gather_seq=sp)
+        m = mlp_block(ctx, cfg, p["mlp"], h)
+        if cfg.post_block_norms:
+            m = L.norm(ctx, cfg, m, p["ln_post_mlp"])
     return x + m, new_cache

@@ -318,9 +318,16 @@ def apply_adamw(
     opt_state,
     replication_factor=None,
 ):
-    """One optimizer step.  grads are LOCAL (pre-DP-reduction).
+    """One optimizer step.  grads are LOCAL (pre-DP-reduction).  Its ops
+    and collectives run under the ``opt:adamw`` name scope.
 
     Returns (new_params, new_opt_state, metrics)."""
+    with jax.named_scope("opt:adamw"):
+        return _apply_adamw(cfg, ctx, params, grads, opt_state,
+                            replication_factor)
+
+
+def _apply_adamw(cfg, ctx, params, grads, opt_state, replication_factor):
     dp_axes = ctx.dp_axes
     step = opt_state["step"]
     lr = lr_at(cfg, step)

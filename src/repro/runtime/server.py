@@ -43,6 +43,12 @@ Both compiled callables come from one ``launch.steps.build_paged_step``
 function used at two shapes, so mixed prompt lengths never trigger a
 per-length recompile.
 
+Each tick's phases run inside profiler spans (``tick=`` in their
+arguments): ``serve.sched.admit`` (expiry, eviction, admission),
+``serve.sched.prefill`` and ``serve.sched.decode``.  The step wrappers of
+``launch.serve`` add ``serve.call.*`` spans inside them, with
+:data:`CALL_ARGS` (a prefill chunk's ``rid``) as arguments.
+
 The seed's wave-batched loop (one whole-prompt prefill per admission,
 lockstep decode over dense ``s_max`` caches) lives on as the measured
 baseline in ``launch.serve.serve`` / ``benchmarks/serve_bench.py``; this
@@ -52,12 +58,22 @@ natural length, never the padded slot budget).
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 from typing import Any, Callable
 
+import jax
 import numpy as np
 
+from repro.launch.compile_cache import compiles
 from repro.models.paging import GARBAGE_PAGE, PageAllocator, PagedConfig
+
+span = jax.profiler.TraceAnnotation
+
+#: span arguments of the compiled-step call in flight: a prefill chunk's
+#: ``rid`` (read by the step wrappers' ``serve.call.*`` spans)
+CALL_ARGS: contextvars.ContextVar[dict] = contextvars.ContextVar(
+    "serve_call_args", default={})
 
 
 @dataclasses.dataclass
@@ -192,8 +208,6 @@ class Server:
         """Device bytes held by the page pools — value leaves plus, for
         quantized pools, the fp16 scale leaves (the honest total the
         quantization ratio is measured against)."""
-        import jax
-
         return sum(int(np.prod(x.shape)) * x.dtype.itemsize
                    for x in jax.tree.leaves(self.caches))
 
@@ -204,8 +218,6 @@ class Server:
         three — plus all non-pool leaves (recurrent state pools) in full.
         Pool leaves are recognized by their (count, num_pages, page_size,
         ...) geometry; scale pools ride along automatically."""
-        import jax
-
         pcfg = self.cfg.paged
         pool_bytes = 0
         total = 0
@@ -219,7 +231,9 @@ class Server:
         return self.alloc.held_pages * per_page + (total - pool_bytes)
 
     def stats(self) -> dict:
-        """Scheduler/pool counters for benches and operators."""
+        """Scheduler/pool counters for benches and operators.
+        ``compiles`` is the process's count of executables built or
+        loaded: it should stop rising after warm-up."""
         hit = (self._prefix_hit_tokens / self._prompt_tokens
                if self._prompt_tokens else 0.0)
         acc = (self._spec_accepted / self._spec_drafts
@@ -239,7 +253,8 @@ class Server:
                 "expired": len(self.expired),
                 "admission_retries": self._admission_retries,
                 "evicted_pages": self._evicted_pages,
-                "reshapes": self._reshapes}
+                "reshapes": self._reshapes,
+                "compiles": compiles()}
 
     def _chunk_rounded(self, n: int) -> int:
         c = self.cfg.prefill_chunk
@@ -247,16 +262,21 @@ class Server:
 
     # -- compiled-step dispatch -------------------------------------------
 
-    def _run(self, tokens, start, table, slot=None):
-        """Call the compiled step with the mode-appropriate signature.
+    def _run(self, tokens, start, table, slot=None, rid=None):
+        """Call the compiled step with the mode-appropriate signature
+        (``rid``: the prefilled request, for the call's spans).
         Returns (tokens, drafts-or-None); caches update in place."""
-        if self.cfg.recurrent:
-            if slot is None:
-                slot = np.full((tokens.shape[0],), self.cfg.batch_slots,
-                               np.int32)
-            out = self.step_fn(tokens, start, table, slot, self.caches)
-        else:
-            out = self.step_fn(tokens, start, table, self.caches)
+        token = CALL_ARGS.set({} if rid is None else {"rid": rid})
+        try:
+            if self.cfg.recurrent:
+                if slot is None:
+                    slot = np.full((tokens.shape[0],), self.cfg.batch_slots,
+                                   np.int32)
+                out = self.step_fn(tokens, start, table, slot, self.caches)
+            else:
+                out = self.step_fn(tokens, start, table, self.caches)
+        finally:
+            CALL_ARGS.reset(token)
         if self.cfg.speculate:
             toks, drafts, self.caches = out
             return toks, drafts
@@ -399,7 +419,8 @@ class Server:
                     table[mask] = GARBAGE_PAGE
                     slot = np.full((B,), B, np.int32)  # sentinel: drop
                     slot[i] = i
-                    toks, _ = self._run(tokens, start, table, slot)
+                    toks, _ = self._run(tokens, start, table, slot,
+                                        rid=s.req.rid)
                     s.fed += 1
                     s.length = s.fed
                     fed += 1
@@ -414,7 +435,8 @@ class Server:
                 table = self.alloc.table()[i: i + 1]
                 start = np.array([s.fed], np.int32)
                 slot = np.array([i], np.int32)
-                toks, drafts = self._run(chunk, start, table, slot)
+                toks, drafts = self._run(chunk, start, table, slot,
+                                         rid=s.req.rid)
                 s.fed += C  # padded tail included; masked by `length`
                 s.length = min(s.fed, len(prompt))
                 fed += 1
@@ -541,11 +563,14 @@ class Server:
         decode tick.  The first two are the degradation ladder's passive
         rungs — under pressure they run every tick so the pool can only
         drain, never wedge."""
-        self._expire()
-        self._evict_pressure()
-        self._admit()
-        self._prefill_some()
-        decoded = self._decode_tick()
+        with span("serve.sched.admit", tick=self.ticks):
+            self._expire()
+            self._evict_pressure()
+            self._admit()
+        with span("serve.sched.prefill", tick=self.ticks):
+            self._prefill_some()
+        with span("serve.sched.decode", tick=self.ticks):
+            decoded = self._decode_tick()
         self.ticks += 1
         return decoded or any(s is not None for s in self.slots)
 

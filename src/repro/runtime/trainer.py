@@ -15,6 +15,12 @@ step (XLA device errors).  The loop's contract:
   - stragglers: per-step wall time is tracked with an EMA; a step slower
     than `straggler_factor` x EMA fires the mitigation hook (on a real
     cluster: re-shard away from the slow host / preemptively checkpoint)
+
+Each phase of a step runs inside a profiler span ``train.loop.<phase>``
+(``step=`` in its arguments): fetch, transfer, compile, dispatch,
+loss_read, bookkeep, checkpoint, recover; ``train.loop.recompile`` marks
+a dispatch that compiled.  With the profiler off a span costs about a
+microsecond.
 """
 from __future__ import annotations
 
@@ -27,8 +33,10 @@ import jax
 
 from repro.checkpoint import manager as ckpt
 from repro.data.pipeline import TokenSource
+from repro.launch.compile_cache import compiles, dispatch_span
 
 log = logging.getLogger("repro.trainer")
+span = jax.profiler.TraceAnnotation
 
 
 def is_device_fault(e: Exception) -> bool:
@@ -152,6 +160,13 @@ class Trainer:
         self.history: list[dict] = []
         self._recovering = False
 
+    @property
+    def compiles(self) -> int:
+        """Executables the process has built or loaded
+        (:func:`repro.launch.compile_cache.compiles`): it should stop
+        rising once the first step has run."""
+        return compiles()
+
     def _restore_or_init(self, shardings=None):
         step = ckpt.latest_step(self.cfg.ckpt_dir)
         if step is None:
@@ -212,31 +227,27 @@ class Trainer:
             try:
                 if fail_injector is not None:
                     fail_injector(step)
-                batch = self.put_batch(self.source.global_batch(step))
+                with span("train.loop.fetch", step=step):
+                    host_batch = self.source.global_batch(step)
+                with span("train.loop.transfer", step=step):
+                    batch = self.put_batch(host_batch)
                 if compiled is not train_step:
-                    try:
-                        compile_ahead(train_step, params, opt_state, batch)
-                    except Exception as e:  # refused by the compiler
-                        raise _CompileError() from e
+                    with span("train.loop.compile", step=step):
+                        try:
+                            compile_ahead(train_step, params, opt_state, batch)
+                        except Exception as e:  # refused by the compiler
+                            raise _CompileError() from e
                     compiled = train_step
                 t0 = self.time_fn()
-                params, opt_state, metrics = train_step(params, opt_state, batch)
-                loss = float(metrics["loss"])
+                with dispatch_span("train.loop.dispatch",
+                                   "train.loop.recompile", step=step):
+                    params, opt_state, metrics = train_step(
+                        params, opt_state, batch)
+                with span("train.loop.loss_read", step=step):
+                    loss = float(metrics["loss"])
                 dt = self.time_fn() - t0
-                if self.watchdog.observe(step, dt):
-                    log.warning("straggler at step %d (%.3fs vs EMA %.3fs)",
-                                step, dt, self.watchdog.ema)
-                    self.mitigation_hook(step)
-                self.history.append({"step": step, "loss": loss, "dt": dt})
-                if self._recovering:
-                    # a post-recovery step committed: the fault was
-                    # transient, so the consecutive-failure budget resets
-                    # (a long run with sporadic recovered faults must not
-                    # eventually trip max_failures)
-                    self._recovering = False
-                    self.failures = 0
-                if step % self.cfg.log_every == 0:
-                    log.info("step %d loss %.4f (%.3fs)", step, loss, dt)
+                with span("train.loop.bookkeep", step=step):
+                    self._bookkeep(step, loss, dt)
             except _CompileError as e:
                 raise e.__cause__ from None
             except RuntimeError as e:  # JaxRuntimeError included
@@ -248,33 +259,57 @@ class Trainer:
                           step, e, self.failures, self.cfg.max_failures)
                 if self.failures > self.cfg.max_failures:
                     raise
-                # full recovery path: rebuild step (fresh executables /
-                # possibly a new mesh) + restore last committed state,
-                # resharded onto whatever mesh the step now targets
-                shardings = None
-                if self.replan is not None:
-                    out = self.replan()
-                    new_step, shardings = (
-                        out if isinstance(out, tuple) else (out, None))
-                    if new_step is not train_step:
-                        # an actual re-plan (the hook returns the live step
-                        # unchanged for a transient fault on an intact
-                        # mesh — that must not count as one)
-                        self.replans.append(step)
-                        # the surviving mesh's step cost is a new
-                        # distribution; judging it against the old mesh's
-                        # EMA would flag every first step (and skew the
-                        # EMA permanently)
-                        self.watchdog.reset()
-                        log.info("elastic re-plan applied at step %d", step)
-                    train_step = new_step
-                else:
-                    train_step = self.build_step()
-                self._recovering = True
-                params, opt_state, step = self._restore_or_init(shardings)
+                with span("train.loop.recover", step=step):
+                    train_step, params, opt_state, step = self._recover(
+                        step, train_step)
                 continue
             step += 1
             every = self.cfg.ckpt_every
             if every and (step % every == 0 or step == self.cfg.total_steps):
-                self._checkpoint(step, params, opt_state)
+                with span("train.loop.checkpoint", step=step):
+                    self._checkpoint(step, params, opt_state)
         return params, opt_state
+
+    def _bookkeep(self, step: int, loss: float, dt: float):
+        """Straggler watchdog, history and log after a committed step."""
+        if self.watchdog.observe(step, dt):
+            log.warning("straggler at step %d (%.3fs vs EMA %.3fs)",
+                        step, dt, self.watchdog.ema)
+            self.mitigation_hook(step)
+        self.history.append({"step": step, "loss": loss, "dt": dt})
+        if self._recovering:
+            # a post-recovery step committed: the fault was transient, so
+            # the consecutive-failure budget resets (a long run with
+            # sporadic recovered faults must not eventually trip
+            # max_failures)
+            self._recovering = False
+            self.failures = 0
+        if step % self.cfg.log_every == 0:
+            log.info("step %d loss %.4f (%.3fs)", step, loss, dt)
+
+    def _recover(self, step: int, train_step):
+        """Full recovery path: rebuild the step (fresh executables,
+        possibly a new mesh) and restore the last committed state,
+        resharded onto whatever mesh the step now targets.  Returns
+        (train_step, params, opt_state, step)."""
+        shardings = None
+        if self.replan is not None:
+            out = self.replan()
+            new_step, shardings = (
+                out if isinstance(out, tuple) else (out, None))
+            if new_step is not train_step:
+                # an actual re-plan (the hook returns the live step
+                # unchanged for a transient fault on an intact mesh — that
+                # must not count as one)
+                self.replans.append(step)
+                # the surviving mesh's step cost is a new distribution;
+                # judging it against the old mesh's EMA would flag every
+                # first step (and skew the EMA permanently)
+                self.watchdog.reset()
+                log.info("elastic re-plan applied at step %d", step)
+            train_step = new_step
+        else:
+            train_step = self.build_step()
+        self._recovering = True
+        params, opt_state, step = self._restore_or_init(shardings)
+        return train_step, params, opt_state, step
