@@ -407,9 +407,13 @@ def init_paged_caches(cfg: ModelConfig, ctx: ATPContext,
     scales with *live tokens*, not ``slots x s_max``.
 
     Per segment kind:
-      attn (dense/moe)   k/v pools ``[count, np, pg, banks, hd]``, the
-                         bank dim sharded over the flat TP axes exactly
-                         like the dense cache;
+      attn (dense/moe)   k/v pools ``[count, np, pg, banks * hd]``,
+                         lane-dense (a last dim of 64 would be padded to
+                         the TPU's 128 lanes), banks the outer factor and
+                         sharded over the flat TP axes, so each device
+                         holds the same banks as in the dense cache;
+                         quantized pools keep one scale per bank,
+                         ``[count, np, pg, banks]``;
       mla (mla_dense/moe) latent pools ``[count, np, pg, rank]`` +
                          ``[count, np, pg, rope_dim]``, TP-replicated
                          (caching the latent is MLA's whole point);
@@ -420,6 +424,9 @@ def init_paged_caches(cfg: ModelConfig, ctx: ATPContext,
                          kinds require ``slots`` (the scheduler's
                          ``batch_slots``) and a per-row ``slot`` id map
                          fed to each step.
+
+    :func:`forward` carries the stacked pools of a paged segment through
+    its layer scan and writes each layer's rows in place.
     """
     n = ctx.tp
     flat = _flat_axes(ctx)
@@ -434,10 +441,10 @@ def init_paged_caches(cfg: ModelConfig, ctx: ATPContext,
 
     def attn_pool(count):
         banks = _attn_cache_shape(cfg, ctx, 1, 1)[2]
-        shape = (count, np_, pg, banks, cfg.hd)
+        shape = (count, np_, pg, banks * cfg.hd)
         c = {"k": arr(shape, pool_dtype), "v": arr(shape, pool_dtype)}
-        sp = {"k": P(None, None, None, flat, None),
-              "v": P(None, None, None, flat, None)}
+        sp = {"k": P(None, None, None, flat),
+              "v": P(None, None, None, flat)}
         if pcfg.quantized:
             # parallel scale pools: same paging, feature dim dropped
             c["k_scale"] = arr((count, np_, pg, banks), jnp.float16)
@@ -736,7 +743,26 @@ def forward(
         sp = params[f"seg{i}"]
         seg_cache = caches.get(f"seg{i}") if caches is not None else None
 
-        if seg.kind in ("dense", "moe", "mla_dense", "mla_moe", "mamba"):
+        if paged is not None and seg.kind in PAGED_CACHE_KINDS:
+            # the stacked page pools ride the scan as carry, each layer
+            # scattering into and gathering from its own rows (``layer``):
+            # the donated pool is written in place, where as xs/ys every
+            # call would slice each layer's pool out and restack it
+            def pbody(carry, xs, _kind=seg.kind, _ctx=sctx):
+                h, aux, pools = carry
+                bp, win, layer = xs
+                h, pools, a = _apply_block(_kind, _ctx, cfg, bp, h,
+                                           positions, plan, win, pools,
+                                           paged=dict(paged, layer=layer))
+                return (h, aux + a, pools), None
+
+            with jax.named_scope(f"seg{i}:{seg.kind}"):
+                (x, aux_total, new_caches[f"seg{i}"]), _ = lax.scan(
+                    pbody, (x, aux_total, seg_cache),
+                    (sp, _gemma_window_array(cfg, seg.count),
+                     jnp.arange(seg.count, dtype=jnp.int32)))
+
+        elif seg.kind in ("dense", "moe", "mla_dense", "mla_moe", "mamba"):
             windows = _gemma_window_array(cfg, seg.count)
 
             def body(carry, xs, _kind=seg.kind, _ctx=sctx):

@@ -80,8 +80,10 @@ def mla_block(
     x,                  # [b, s, h/d2]
     positions,          # [b, s]
     cache=None,         # decode: dict(ckv=[b,S,rank], krope=[b,S,rd], len=..)
-                        # or paged pools dict(ckv=[np,pg,rank], krope=...)
-    paged=None,         # paged serving: dict(table=[b,mp], start=[b])
+                        # or paged pools dict(ckv=[np,pg,rank], krope=...),
+                        # stacked [count, np, ...] when paged["layer"]
+    paged=None,         # paged serving: dict(table=[b,mp], start=[b],
+                        # optional layer=scalar into stacked pools)
 ):
     """Returns ([b, s, h/d2], new_cache)."""
     m = cfg.mla
@@ -133,19 +135,22 @@ def mla_block(
             # paged serving: the latent pools are position-paged exactly
             # like K/V; scatter this run, gather the slot's mapped pages
             table, start = paged["table"], paged["start"]
+            layer = paged.get("layer")
             pd = paging.pool_page_dtype(cache["ckv"])
             pckv, pckv_s = paging.append_tokens_q(
-                cache["ckv"], cache.get("ckv_scale"), table, start, ckv, pd)
+                cache["ckv"], cache.get("ckv_scale"), table, start, ckv, pd,
+                layer)
             pkr, pkr_s = paging.append_tokens_q(
                 cache["krope"], cache.get("krope_scale"), table, start,
-                k_pe_new, pd)
+                k_pe_new, pd, layer)
             new_cache = {"ckv": pckv, "krope": pkr}
             if pckv_s is not None:
                 new_cache["ckv_scale"], new_cache["krope_scale"] = pckv_s, pkr_s
             cckv = paging.gather_pages_q(pckv, pckv_s, table,
-                                         out_dtype=ckv.dtype)  # [b,S_alloc,rank]
+                                         out_dtype=ckv.dtype,
+                                         layer=layer)  # [b,S_alloc,rank]
             ckr = paging.gather_pages_q(pkr, pkr_s, table,
-                                        out_dtype=k_pe_new.dtype)
+                                        out_dtype=k_pe_new.dtype, layer=layer)
             klen = start                                 # [b] per-slot
         else:
             klen = cache["len"]

@@ -79,8 +79,8 @@ class PagedConfig:
 
     ``page_dtype`` picks the pool storage format: "bf16" stores the model
     dtype verbatim; "int8"/"fp8" store 1 byte per element plus an fp16
-    per-position scale pool (symmetric, shared across the feature dim —
-    see :func:`quantize_tokens`).  Quant/dequant happens at the pool
+    per-position scale pool (symmetric, one scale per position and KV
+    bank — see :func:`quantize_tokens`).  Quant/dequant happens at the pool
     boundary (:func:`append_tokens_q` / :func:`gather_pages_q`); attention
     itself always runs on dequantized full-width values.
     """
@@ -362,24 +362,34 @@ class PageAllocator:
 # ---------------------------------------------------------------------------
 
 
-def gather_pages(pages, table):
+def gather_pages(pages, table, layer=None):
     """Materialize each slot's mapped positions from the pool.
 
-    pages [num_pages, page, ...feat]; table [B, mp] ->
-    [B, mp * page, ...feat].  Unmapped entries read the garbage page;
-    callers mask those positions by the slot's length (exactly like the
-    dense cache masks positions beyond ``len``), so the values never
-    reach a softmax unmasked.
+    pages [num_pages, page, ...feat] (or [count, num_pages, page, ...feat]
+    read at ``layer``); table [B, mp] -> [B, mp * page, ...feat].
+    Unmapped entries read the garbage page; callers mask those positions
+    by the slot's length (exactly like the dense cache masks positions
+    beyond ``len``), so the values never reach a softmax unmasked.
+
+    A stacked pool is read with one gather over the layers' pages laid
+    end to end (``[count * num_pages, page, ...]``, a bitcast, not a
+    slice), the table offset to ``layer``'s rows: so a layer scan can
+    carry the pool and update it in place, where slicing one layer out
+    and restacking it would copy it on every call.
     """
+    if layer is not None:
+        table = table + layer * pages.shape[1]
+        pages = pages.reshape((-1,) + pages.shape[2:])
     g = jnp.take(pages, table, axis=0)            # [B, mp, page, ...]
     return g.reshape((table.shape[0], table.shape[1] * pages.shape[1])
                      + pages.shape[2:])
 
 
-def append_tokens(pages, table, start, values):
+def append_tokens(pages, table, start, values, layer=None):
     """Scatter per-slot token runs into the pool through the page table.
 
-    pages [num_pages, page, ...feat]; table [B, mp]; start [B] (each
+    pages [num_pages, page, ...feat] (or [count, num_pages, page, ...feat]
+    written at ``layer``, in place); table [B, mp]; start [B] (each
     slot's first logical position for this run); values [B, s, ...feat].
     Position p of slot b lands in physical page ``table[b, p // page]``
     at offset ``p % page``.  Writes beyond a slot's valid length (padded
@@ -388,7 +398,7 @@ def append_tokens(pages, table, start, values):
     the same slot or are the garbage page — never read unmasked.
     """
     B, s = values.shape[:2]
-    page = pages.shape[1]
+    page = pages.shape[1 if layer is None else 2]
     pos = start[:, None] + jnp.arange(s, dtype=start.dtype)[None, :]  # [B,s]
     logical = pos // page
     # clamp: positions past the table width scatter to the garbage page
@@ -398,7 +408,10 @@ def append_tokens(pages, table, start, values):
         ok, jnp.take_along_axis(table, jnp.minimum(
             logical, table.shape[1] - 1), axis=1), GARBAGE_PAGE)
     off = pos % page
-    return pages.at[phys, off].set(values.astype(pages.dtype))
+    values = values.astype(pages.dtype)
+    if layer is None:
+        return pages.at[phys, off].set(values)
+    return pages.at[layer, phys, off].set(values)
 
 
 # ---------------------------------------------------------------------------
@@ -439,9 +452,11 @@ def quantize_tokens(values, page_dtype: str):
     """values [..., feat] -> (quantized [..., feat], fp16 scales [...]).
 
     Symmetric per-position quantization: one scale per token position
-    (shared over the trailing feature dim), so the scale pool is a
-    parallel paged tensor with the feature dim dropped and rides the same
-    page tables through :func:`append_tokens` / :func:`gather_pages`.
+    (and per KV bank: :func:`append_tokens_q` puts the bank before the
+    trailing dim), shared over the trailing feature dim, so the scale
+    pool is a parallel paged tensor with the feature dim dropped and rides
+    the same page tables through :func:`append_tokens` /
+    :func:`gather_pages`.
     Scales are stored fp16 — at head_dim >= 32 an f32 scale alone would
     eat the margin below a 1.8x pool-byte reduction.
     """
@@ -458,28 +473,36 @@ def quantize_tokens(values, page_dtype: str):
     return q, scale.astype(jnp.float16)
 
 
-def append_tokens_q(pages, scales, table, start, values, page_dtype: str):
+def append_tokens_q(pages, scales, table, start, values, page_dtype: str,
+                    layer=None):
     """Quant-aware :func:`append_tokens`: returns (new_pages, new_scales).
 
     ``scales is None`` means the pool is full-width — plain append, scale
-    pool untouched.  Otherwise the values are quantized per position and
-    both the value pool and the parallel scale pool are scattered through
-    the same table."""
+    pool untouched.  Otherwise the values are quantized with one scale
+    per element of the scale pool's position (a lane-dense attention
+    pool ``[.., banks * hd]`` has scales ``[.., banks]``: one per bank),
+    and both the value pool and the parallel scale pool are scattered
+    through the same table."""
     if scales is None:
-        return append_tokens(pages, table, start, values), None
-    q, s = quantize_tokens(values, page_dtype)
-    return (append_tokens(pages, table, start, q),
-            append_tokens(scales, table, start, s))
+        return append_tokens(pages, table, start, values, layer), None
+    feat = scales.shape[2 if layer is None else 3:]
+    q, s = quantize_tokens(values.reshape(values.shape[:2] + feat + (-1,)),
+                           page_dtype)
+    return (append_tokens(pages, table, start, q.reshape(values.shape),
+                          layer),
+            append_tokens(scales, table, start, s, layer))
 
 
-def gather_pages_q(pages, scales, table, out_dtype=jnp.bfloat16):
+def gather_pages_q(pages, scales, table, out_dtype=jnp.bfloat16, layer=None):
     """Quant-aware :func:`gather_pages`: dequantize at the pool boundary.
 
     ``scales is None`` -> plain gather.  Otherwise gathers values and
-    scales through the same table and returns ``values * scale`` in
-    ``out_dtype`` (attention always runs full-width)."""
+    scales through the same table and returns ``values * scale`` (each
+    scale over its run of the feature dim) in ``out_dtype`` (attention
+    always runs full-width)."""
     if scales is None:
-        return gather_pages(pages, table)
-    v = gather_pages(pages, table).astype(jnp.float32)
-    s = gather_pages(scales, table).astype(jnp.float32)
-    return (v * s[..., None]).astype(out_dtype)
+        return gather_pages(pages, table, layer)
+    v = gather_pages(pages, table, layer).astype(jnp.float32)
+    s = gather_pages(scales, table, layer).astype(jnp.float32)
+    return (v.reshape(s.shape + (-1,)) * s[..., None]).reshape(
+        v.shape).astype(out_dtype)

@@ -110,8 +110,10 @@ def attn_block(
     plan: L.AttnPlan,
     layer_window: int = 0,  # sliding window for this layer (0 = global)
     cache=None,             # decode: dict(k=[b,S,kvb,hd], v=..., len=scalar)
-                            # or paged pools dict(k=[np,pg,kvb,hd], v=...)
-    paged=None,             # paged serving: dict(table=[b,mp], start=[b])
+                            # or paged pools dict(k=[np,pg,kvb*hd], v=...),
+                            # stacked [count, np, ...] when paged["layer"]
+    paged=None,             # paged serving: dict(table=[b,mp], start=[b],
+                            # optional layer=scalar into stacked pools)
 ):
     """Returns (attn output [b, s, h/d2], new_cache)."""
     # f1: column-first q/k/v projections, one fused boundary psum(ax2)
@@ -165,18 +167,28 @@ def attn_block(
     if decode and paged is not None:
         # paged serving: scatter this run's k/v through the slot page
         # tables, then attend over each slot's MAPPED pages only (per-slot
-        # positions; garbage-page reads are masked by start + s)
+        # positions; garbage-page reads are masked by start + s).  The
+        # pools are lane-dense, [.., banks * hd]: k/v go in flattened and
+        # come back out as [b, S, banks, hd]
         table, start = paged["table"], paged["start"]
+        layer = paged.get("layer")
         pd = paging.pool_page_dtype(cache["k"])
+        flat = k.shape[:2] + (-1,)
         ck, cks = paging.append_tokens_q(cache["k"], cache.get("k_scale"),
-                                         table, start, k, pd)
+                                         table, start, k.reshape(flat), pd,
+                                         layer)
         cv, cvs = paging.append_tokens_q(cache["v"], cache.get("v_scale"),
-                                         table, start, v, pd)
+                                         table, start, v.reshape(flat), pd,
+                                         layer)
         new_cache = {"k": ck, "v": cv}
         if cks is not None:
             new_cache["k_scale"], new_cache["v_scale"] = cks, cvs
-        kk = paging.gather_pages_q(ck, cks, table, out_dtype=k.dtype)
-        vv = paging.gather_pages_q(cv, cvs, table, out_dtype=v.dtype)
+        kk = paging.gather_pages_q(ck, cks, table, out_dtype=k.dtype,
+                                   layer=layer)
+        vv = paging.gather_pages_q(cv, cvs, table, out_dtype=v.dtype,
+                                   layer=layer)
+        kk = kk.reshape(kk.shape[:2] + k.shape[2:])
+        vv = vv.reshape(vv.shape[:2] + v.shape[2:])
         o = L.attention_core(cfg, q, kk, vv, q_offset=start,
                              kv_len=start + q.shape[1], window=layer_window)
     elif decode:

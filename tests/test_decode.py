@@ -256,3 +256,62 @@ def test_prefill_into_cache_matches_stepwise():
         logits_step, caches2 = g(params, tokens[:, t: t + 1], jnp.int32(t), caches2)
     np.testing.assert_allclose(np.asarray(logits_bulk), np.asarray(logits_step),
                                rtol=5e-3, atol=5e-3)
+
+
+@pytest.mark.parametrize("page_dtype", ["bf16", "int8"])
+def test_layer_indexed_pool_access_matches_per_layer_slice(page_dtype):
+    """append/gather at ``layer`` on a stacked pool == slicing that layer
+    out, writing it and restacking it.  Positions past the table and
+    unmapped entries go to the layer's own garbage page; quantized pools
+    keep one scale per KV bank of the lane-dense [.., banks * hd] row."""
+    from repro.models import paging
+
+    count, np_, pg, banks, hd = 3, 10, 4, 2, 8
+    rng = np.random.default_rng(0)
+    store = paging.page_store_dtype(page_dtype) or jnp.float32
+    pool = jnp.asarray(rng.normal(size=(count, np_, pg, banks * hd)) * 4,
+                       store)
+    scales = (jnp.asarray(rng.uniform(size=(count, np_, pg, banks)),
+                          jnp.float16) if store != jnp.float32 else None)
+    # slot 0 runs past its 3-page table; slot 1 maps one page of three
+    table = jnp.asarray([[3, 5, 8], [7, 0, 0]], jnp.int32)
+    start = jnp.asarray([6, 2], jnp.int32)
+    values = rng.normal(size=(2, 7, banks, hd))
+    values[..., 1, :] *= 100.0      # banks of very different scale
+    values = jnp.asarray(values.reshape(2, 7, banks * hd), jnp.float32)
+    g = paging.GARBAGE_PAGE
+
+    for layer in range(count):
+        got, got_s = paging.append_tokens_q(pool, scales, table, start,
+                                            values, page_dtype,
+                                            layer=jnp.int32(layer))
+        one, one_s = paging.append_tokens_q(
+            pool[layer], None if scales is None else scales[layer], table,
+            start, values, page_dtype)
+        ref = pool.at[layer].set(one)
+        ref_s = None if scales is None else scales.at[layer].set(one_s)
+        # the garbage page's offset 0 takes three writes, in any order
+        keep = np.ones(pool.shape[:3], bool)
+        keep[layer, g, 0] = False
+        np.testing.assert_array_equal(np.asarray(got)[keep],
+                                      np.asarray(ref)[keep])
+        if scales is not None:
+            np.testing.assert_array_equal(np.asarray(got_s)[keep],
+                                          np.asarray(ref_s)[keep])
+        read = paging.gather_pages_q(got, got_s, table, jnp.float32,
+                                     layer=jnp.int32(layer))
+        want = paging.gather_pages_q(ref[layer], None if scales is None
+                                     else ref_s[layer], table, jnp.float32)
+        assert read.shape == (2, 3 * pg, banks * hd)
+        pos = np.arange(3 * pg)
+        same = ~((np.asarray(table)[:, pos // pg] == g) & (pos % pg == 0))
+        np.testing.assert_array_equal(np.asarray(read)[same],
+                                      np.asarray(want)[same])
+        # what was written reads back; int8 within half a step of its
+        # bank's own scale (one scale per row would lose the small bank)
+        tol = 0.0 if scales is None else 0.5 / 127 * 1.01
+        for b, lo, n in ((0, 6, 6), (1, 2, 2)):
+            v = np.asarray(values[b, :n]).reshape(n, banks, hd)
+            r = np.asarray(read[b, lo:lo + n]).reshape(n, banks, hd)
+            amax = np.abs(v).max(-1, keepdims=True)
+            assert np.all(np.abs(r - v) <= tol * amax + 1e-6)

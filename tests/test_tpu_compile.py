@@ -8,6 +8,7 @@ result.  The topology is described inside a fixture, never at import: only
 the process that runs these tests loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +29,9 @@ HBM_BYTES = 16 * 10**9
 
 #: the serving geometry chip_smoke.py runs: 8 slots x 64 pages of 16
 PAGED = PagedConfig(page_size=16, num_pages=1 + 8 * 64, pages_per_slot=64)
+
+#: the benchmark's serve cell: 32 slots x 160 pages of 16 in 4097 pages
+SERVE_CELL = PagedConfig(page_size=16, num_pages=4097, pages_per_slot=160)
 
 
 @pytest.fixture(scope="module")
@@ -99,12 +103,12 @@ def _mesh(plan, topo):
     return t, t.build(topo.devices[: t.size])
 
 
-def _paged_args(cfg, info, b, s):
+def _paged_args(cfg, info, b, s, pcfg=PAGED):
     i32 = jnp.int32
-    caches, _ = lm.init_paged_caches(cfg, info.ctx, PAGED, abstract=True)
+    caches, _ = lm.init_paged_caches(cfg, info.ctx, pcfg, abstract=True)
     return (lm.abstract_params(cfg), jax.ShapeDtypeStruct((b, s), i32),
             jax.ShapeDtypeStruct((b,), i32),
-            jax.ShapeDtypeStruct((b, PAGED.pages_per_slot), i32), caches)
+            jax.ShapeDtypeStruct((b, pcfg.pages_per_slot), i32), caches)
 
 
 def _device_bytes(compiled) -> int:
@@ -122,6 +126,40 @@ def test_paged_step_qwen1_5_0_5b_compiles_on_one_chip(topo, b, s):
                                   plan=plan)
     compiled = step.lower(*_paged_args(cfg, info, b, s)).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+#: HLO ops that move a whole buffer; over the page pool each one is a copy
+#: of gigabytes on every call (the in-place ``scatter`` is not one of them)
+_COPYING_OPS = ("copy", "dynamic-slice", "dynamic-update-slice",
+                "custom-call")
+
+
+@pytest.mark.parametrize("b,s", [(1, 512), (32, 1)],
+                         ids=["prefill_chunk", "decode_tick"])
+def test_paged_step_keeps_pool_in_place(topo, b, s):
+    """At the serve cell's geometry the layer scan writes the donated page
+    pool in place: no op copies the stacked pool or one layer of it, and
+    the temp bytes stay below one stacked pool tensor."""
+    cfg = get_config("qwen1.5-0.5b")
+    plan = ParallelPlan(d1=1, d2=1, dp=1)
+    t, mesh = _mesh(plan, topo)
+    step, info = build_paged_step(cfg, t, paged_cfg=SERVE_CELL, mesh=mesh,
+                                  plan=plan)
+    args = _paged_args(cfg, info, b, s, SERVE_CELL)
+    pool = args[-1]["seg0"]["k"]
+    compiled = step.lower(*args).compile()
+    pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+    shapes = {"[" + ",".join(map(str, shape)) + "]"
+              for shape in (pool.shape, pool.shape[1:],
+                            (pool.shape[0] * pool.shape[1],) + pool.shape[2:])}
+    text = compiled.as_text()
+    assert any(f"{sh}{{" in text for sh in shapes), "pool not in program"
+    op = re.compile(r"=\s*(.*?)\s+(" + "|".join(_COPYING_OPS) + r")\(")
+    copies = [line.strip()[:200] for line in text.splitlines()
+              if (m := op.search(line))
+              and any(sh in m.group(1) for sh in shapes)]
+    assert not copies, copies[:4]
 
 
 def test_train_step_qwen1_5_0_5b_fits_one_chip(topo):
