@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import matmul as _mm
+from repro.kernels import paged_attention as _pa
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssd_scan as _ssd
 
@@ -35,6 +36,18 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
                             softcap=softcap, block_q=block_q, block_k=block_k,
                             interpret=interpret)
     return o.reshape(b, hq, sq, d).transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
+def paged_attention(q, k_pages, v_pages, table, lengths, layer, window, *,
+                    softcap=0.0, interpret=False):
+    """Decode attention of one query row per slot over its paged K/V,
+    read in place: q [b, hq, hd]; lane-dense pools [layers, np, page,
+    banks * hd]; table [b, mp]; lengths [b]; layer and window (0 =
+    global) scalars -> [b, hq, hd]."""
+    return _pa.paged_decode_attention(q, k_pages, v_pages, table, lengths,
+                                      layer, window, softcap=softcap,
+                                      interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))

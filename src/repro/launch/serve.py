@@ -176,6 +176,7 @@ def _build_paged_step_fn(cfg, scfg: ServerConfig, params, topo,
         with span("serve.call.read", **tags):
             return (*(np.asarray(t) for t in toks), caches)
 
+    step_fn.decode_attention = info.decode_attention
     return step_fn, init_caches, info
 
 

@@ -30,6 +30,8 @@ class StepInfo:
     bspecs: Any
     ospecs: Any = None
     cache_specs: Any = None
+    #: paged steps: how a decode tick attends, "paged_kernel" or "gather"
+    decode_attention: str | None = None
 
     def sharding(self, spec_tree):
         return jax.tree.map(
@@ -254,6 +256,12 @@ def build_paged_step(cfg: ModelConfig, topo: MeshTopo | None = None,
 
     ``decode=True`` context resolution applies the plan's decode
     sub-plan knobs (boundary_mode, chunks=1) and masks seq_parallel.
+
+    On a TPU mesh a one-row call over full-width bf16 attention pools
+    attends through the paged attention kernel, which reads each slot's
+    live pages in place; prefill chunks, verify rows, quantized pools,
+    MLA latents and every other platform gather the pages.
+    ``info.decode_attention`` names the decode tick's path.
     """
     from repro.models.paging import PagedConfig
 
@@ -277,13 +285,21 @@ def build_paged_step(cfg: ModelConfig, topo: MeshTopo | None = None,
     _, cache_specs = lm.init_paged_caches(cfg, ctx, pcfg, abstract=True,
                                           slots=slots)
     tspec = P(None, None)
-    info = StepInfo(mesh, ctx, pspecs, tspec, cache_specs=cache_specs)
+    # the mesh's devices, not jax.default_backend(): a step may be built
+    # for described TPU devices from a CPU process
+    tpu = mesh.devices.flat[0].platform == "tpu"
+    attends = any(s.kind in ("dense", "moe", "zamba") for s in segments(cfg))
+    kernel = (tpu and attends and not speculate and not pcfg.quantized
+              and jnp.dtype(cfg.dtype) == jnp.bfloat16)
+    info = StepInfo(mesh, ctx, pspecs, tspec, cache_specs=cache_specs,
+                    decode_attention="paged_kernel" if kernel else "gather")
 
     if needs_slot:
         def local(params, tokens, start, table, slot, caches):
             logits, new_caches = lm.paged_step(ctx, cfg, params, tokens,
                                                start, table, caches,
-                                               slot=slot)
+                                               slot=slot,
+                                               decode_kernel=kernel)
             return _greedy_pick(ctx, cfg, logits), new_caches
 
         fn = shard_map(local, mesh=mesh,
@@ -336,7 +352,8 @@ def build_paged_step(cfg: ModelConfig, topo: MeshTopo | None = None,
 
     def local(params, tokens, start, table, caches):
         logits, new_caches = lm.paged_step(ctx, cfg, params, tokens, start,
-                                           table, caches)
+                                           table, caches,
+                                           decode_kernel=kernel)
         return _greedy_pick(ctx, cfg, logits), new_caches
 
     fn = shard_map(local, mesh=mesh,

@@ -975,7 +975,8 @@ def decode_step(ctx: ATPContext, cfg: ModelConfig, params, tokens, pos, caches):
 
 
 def paged_step(ctx: ATPContext, cfg: ModelConfig, params, tokens, start,
-               table, caches, slot=None, with_hidden: bool = False):
+               table, caches, slot=None, with_hidden: bool = False,
+               decode_kernel: bool = False):
     """One paged cache-write step — decode tick AND prefill chunk.
 
     tokens [b, s] (decode: b=slots, s=1; prefill chunk: b=1, s=chunk);
@@ -990,6 +991,9 @@ def paged_step(ctx: ATPContext, cfg: ModelConfig, params, tokens, start,
     all positions keeps one compiled step reusable across prompt lengths:
     the scheduler picks the logits of the last *valid* token of a padded
     final chunk on the host, instead of forcing a recompile per length.
+    ``decode_kernel`` (the step's choice, ``StepInfo.decode_attention``)
+    lets one-row calls read the attention pools through the paged
+    attention kernel (``transformer.attn_block``).
     """
     b, s = tokens.shape
     prange = start[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
@@ -997,7 +1001,7 @@ def paged_step(ctx: ATPContext, cfg: ModelConfig, params, tokens, start,
         positions = jnp.broadcast_to(prange[None], (3, b, s))
     else:
         positions = prange
-    paged = {"table": table, "start": start}
+    paged = {"table": table, "start": start, "kernel": decode_kernel}
     if slot is not None:
         paged["slot"] = slot
     h, new_caches, _, _ = forward(ctx, cfg, params, tokens, positions,

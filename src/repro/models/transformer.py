@@ -19,6 +19,7 @@ from jax import lax
 from repro.configs.base import ModelConfig
 from repro.core.atp import (ATPContext, atp_boundary, atp_linear, atp_scope,
                             grad_sync, shard_slice)
+from repro.kernels import ops
 from repro.models import layers as L
 from repro.models import paging
 
@@ -113,7 +114,9 @@ def attn_block(
                             # or paged pools dict(k=[np,pg,kvb*hd], v=...),
                             # stacked [count, np, ...] when paged["layer"]
     paged=None,             # paged serving: dict(table=[b,mp], start=[b],
-                            # optional layer=scalar into stacked pools)
+                            # optional layer=scalar into stacked pools,
+                            # kernel=True: one-row calls read full-width
+                            # pools through the paged attention kernel)
 ):
     """Returns (attn output [b, s, h/d2], new_cache)."""
     # f1: column-first q/k/v projections, one fused boundary psum(ax2)
@@ -183,14 +186,24 @@ def attn_block(
         new_cache = {"k": ck, "v": cv}
         if cks is not None:
             new_cache["k_scale"], new_cache["v_scale"] = cks, cvs
-        kk = paging.gather_pages_q(ck, cks, table, out_dtype=k.dtype,
-                                   layer=layer)
-        vv = paging.gather_pages_q(cv, cvs, table, out_dtype=v.dtype,
-                                   layer=layer)
-        kk = kk.reshape(kk.shape[:2] + k.shape[2:])
-        vv = vv.reshape(vv.shape[:2] + v.shape[2:])
-        o = L.attention_core(cfg, q, kk, vv, q_offset=start,
-                             kv_len=start + q.shape[1], window=layer_window)
+        if paged.get("kernel") and q.shape[1] == 1:
+            # a decode tick: the kernel reads each slot's live pages in
+            # place, no gather of the whole table width
+            with jax.named_scope("attn:paged"):
+                o = ops.paged_attention(
+                    q[:, 0], ck, cv, table, start + 1,
+                    0 if layer is None else layer, layer_window,
+                    softcap=cfg.attn_softcap)[:, None]
+        else:
+            kk = paging.gather_pages_q(ck, cks, table, out_dtype=k.dtype,
+                                       layer=layer)
+            vv = paging.gather_pages_q(cv, cvs, table, out_dtype=v.dtype,
+                                       layer=layer)
+            kk = kk.reshape(kk.shape[:2] + k.shape[2:])
+            vv = vv.reshape(vv.shape[:2] + v.shape[2:])
+            o = L.attention_core(cfg, q, kk, vv, q_offset=start,
+                                 kv_len=start + q.shape[1],
+                                 window=layer_window)
     elif decode:
         # append this step's k/v at cache['len'] (s >= 1: also serves as
         # prefill-into-cache for the serving loop)

@@ -233,7 +233,9 @@ class Server:
     def stats(self) -> dict:
         """Scheduler/pool counters for benches and operators.
         ``compiles`` is the process's count of executables built or
-        loaded: it should stop rising after warm-up."""
+        loaded: it should stop rising after warm-up.
+        ``decode_attention`` is how the step's decode tick attends
+        ("paged_kernel" or "gather"; None for a step that does not say)."""
         hit = (self._prefix_hit_tokens / self._prompt_tokens
                if self._prompt_tokens else 0.0)
         acc = (self._spec_accepted / self._spec_drafts
@@ -254,6 +256,8 @@ class Server:
                 "admission_retries": self._admission_retries,
                 "evicted_pages": self._evicted_pages,
                 "reshapes": self._reshapes,
+                "decode_attention": getattr(self.step_fn,
+                                            "decode_attention", None),
                 "compiles": compiles()}
 
     def _chunk_rounded(self, n: int) -> int:

@@ -79,6 +79,12 @@ def _kernel_case(name, s):
     if name == "matmul":                  # qwen1.5-0.5b mlp up-projection
         return ops.matmul, (_sds((4096, 1024), bf, s),
                             _sds((1024, 2816), bf, s))
+    if name == "paged_attention":         # the serve cell's decode tick
+        i32 = jnp.int32
+        pool = _sds((24, 4097, 16, 1024), bf, s)
+        return ops.paged_attention, (
+            _sds((32, 16, 64), bf, s), pool, pool, _sds((32, 160), i32, s),
+            _sds((32,), i32, s), _sds((), i32, s), _sds((), i32, s))
     if name == "ssd_scan":                # zamba2-7b mamba2 heads
         b, sq, nh, hd, ds = 1, 2048, 112, 64, 64
         f32 = jnp.float32
@@ -91,7 +97,8 @@ def _kernel_case(name, s):
 
 @pytest.mark.parametrize("name", ["flash_attention_d64",
                                   "flash_attention_d128", "rmsnorm",
-                                  "matmul", "ssd_scan"])
+                                  "matmul", "ssd_scan",
+                                  "paged_attention"])
 def test_pallas_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernel_case(name, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
@@ -139,7 +146,9 @@ _COPYING_OPS = ("copy", "dynamic-slice", "dynamic-update-slice",
 def test_paged_step_keeps_pool_in_place(topo, b, s):
     """At the serve cell's geometry the layer scan writes the donated page
     pool in place: no op copies the stacked pool or one layer of it, and
-    the temp bytes stay below one stacked pool tensor."""
+    the temp bytes stay below one stacked pool tensor.  The decode tick
+    reads the pool through the paged attention kernel; a prefill chunk
+    does not."""
     cfg = get_config("qwen1.5-0.5b")
     plan = ParallelPlan(d1=1, d2=1, dp=1)
     t, mesh = _mesh(plan, topo)
@@ -160,6 +169,8 @@ def test_paged_step_keeps_pool_in_place(topo, b, s):
               if (m := op.search(line))
               and any(sh in m.group(1) for sh in shapes)]
     assert not copies, copies[:4]
+    assert info.decode_attention == "paged_kernel"
+    assert ("tpu_custom_call" in text) == (s == 1)
 
 
 def test_train_step_qwen1_5_0_5b_fits_one_chip(topo):
@@ -187,3 +198,27 @@ def test_paged_step_qwen3_8b_compiles_on_2x2_mesh(topo):
                                   plan=plan)
     compiled = step.lower(*_paged_args(cfg, info, 8, 1)).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("arch,page_dtype,dtype,want", [
+    ("qwen1.5-0.5b", "bf16", "bfloat16", "paged_kernel"),
+    ("qwen3-8b", "bf16", "bfloat16", "paged_kernel"),      # GQA, qk-norm
+    ("qwen1.5-0.5b", "int8", "bfloat16", "gather"),        # quantized pool
+    ("qwen1.5-0.5b", "bf16", "float32", "gather"),         # f32 model
+    ("deepseek-v3-671b", "bf16", "bfloat16", "gather"),    # MLA latents
+])
+def test_paged_decode_attention_path(topo, arch, page_dtype, dtype, want):
+    """Which path a decode tick built for the TPU takes, from what the
+    step can observe: the kernel is in the lowered decode program exactly
+    when the step's info says so."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config(arch).reduced(), dtype=dtype)
+    plan = ParallelPlan(d1=1, d2=1, dp=1)
+    t, mesh = _mesh(plan, topo)
+    pcfg = dataclasses.replace(PAGED, page_dtype=page_dtype)
+    step, info = build_paged_step(cfg, t, paged_cfg=pcfg, mesh=mesh,
+                                  plan=plan)
+    assert info.decode_attention == want
+    text = step.lower(*_paged_args(cfg, info, 8, 1, pcfg)).as_text()
+    assert ("tpu_custom_call" in text) == (want == "paged_kernel")
